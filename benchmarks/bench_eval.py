@@ -98,6 +98,9 @@ def main(smoke: bool = False, rows: int = 512, inpaint_rows: int = 8,
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--rows", type=int, default=512)

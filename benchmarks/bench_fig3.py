@@ -80,4 +80,7 @@ def main(quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     main()

@@ -193,6 +193,9 @@ def main(smoke: bool = False, components: int = 0, batch: int = 0,
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny component, parity-gated only (CI profile)")

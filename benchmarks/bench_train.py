@@ -418,6 +418,9 @@ def main(smoke: bool = False, archs=None, batch: int = 0, steps: int = 0,
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny model, parity-gated only (CI profile)")

@@ -1,20 +1,21 @@
-"""repro: Einsum Networks (Peharz et al., ICML 2020) as a production
-multi-pod JAX framework.
+"""repro: Einsum Networks (Peharz et al., ICML 2020) as a JAX framework for
+training and serving tractable probabilistic circuits on TPU.
 
 Subpackages:
   core        the paper's contribution (einsum-layer PCs, autodiff-EM)
   kernels     Pallas TPU kernels + jnp oracles
-  models      LM substrate (the 10 assigned architectures)
   configs     architecture registry (--arch <id>)
-  data        synthetic datasets + sharded pipeline
-  optim       AdamW (quantizable state), gradient compression
+  data        image datasets, synthetic data, sharded pipeline
+  train       the compiled EM step and its training loop
+  serve       the batched exact-inference engine
+  mixture     mixtures of EiNets over k-means clusters
+  eval        held-out metrics, inpainting, the image-eval workbench
+  optim       AdamW with quantizable state, gradient compression
   checkpoint  atomic async checkpoints
   dist        sharding rules, fault tolerance, elasticity
-  launch      production mesh, dry-run, train/serve drivers
+  analysis    circuit/plan verifier, recompile sentry, AST lint
+  obs         host tracing, metrics, device-side health telemetry
+  launch      mesh, train/serve/eval drivers, compile cache
 """
-
-from repro import _jax_compat as _jax_compat_lib
-
-_jax_compat_lib.install()  # uniform mesh API across the supported jax range
 
 __version__ = "1.0.0"

@@ -54,7 +54,7 @@ def _leaf_aval(leaf: Any) -> Tuple[Any, ...]:
     import jax
 
     try:
-        aval = jax.core.get_aval(leaf)
+        aval = jax.typeof(leaf)
     except TypeError:
         return ("static", repr(leaf), False)
     return (

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from repro.core.einet import EiNet
 from repro.dist import sharding as sharding_lib
+from repro.core import layers
 from repro.core.layers import normalize_einsum_weights, normalize_mixing_weights
 
 
@@ -127,7 +128,8 @@ def em_statistics(
     cst = sharding_lib.constraint
     g_pairs = cst(g_leaf[:, ls.pair_leaf, :], ("batch", "einet_nodes", None))
     t_pairs = cst(t[:, ls.pair_var, :], ("batch", "einet_nodes", None))
-    s_phi_pairs = cst(jnp.einsum("bpk,bpt->pkt", g_pairs, t_pairs),
+    s_phi_pairs = cst(jnp.einsum("bpk,bpt->pkt", g_pairs, t_pairs,
+                                 precision=layers.PRECISION),
                       ("einet_nodes", None, None))
     s_den_pairs = cst(jnp.sum(g_pairs, axis=0), ("einet_nodes", None))
     s_phi, s_den = leaf_scatter(model, s_phi_pairs, s_den_pairs)

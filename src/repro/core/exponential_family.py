@@ -34,6 +34,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import layers
+
 
 @dataclasses.dataclass(frozen=True)
 class ExponentialFamily:
@@ -92,7 +94,8 @@ class ExponentialFamily:
         theta = self.expectation_to_natural(phi)  # (D, K, R, T)
         t = self.sufficient_statistics(x)  # (B, D, T)
         # inner product T(x)^T theta, broadcast over (K, R)
-        dot = jnp.einsum("bdt,dkrt->bdkr", t, theta)
+        dot = jnp.einsum("bdt,dkrt->bdkr", t, theta,
+                         precision=layers.PRECISION)
         a = self.log_normalizer(theta)  # (D, K, R)
         return self.log_h(x)[:, :, None, None] + dot - a[None]
 

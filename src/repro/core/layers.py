@@ -20,6 +20,17 @@ import numpy as np
 # would produce NaNs through max/exp.
 NEG_INF = -1e30
 
+# Precision of every float32 contraction the model makes in XLA: the einsum
+# layer below, the leaf log-densities (exponential_family) and the leaf E-step
+# statistics (core.em, mixture.train).  On TPU the default runs an f32 matmul
+# as one bf16 pass; on a v5e that put einet-pd-svhn's leaf log-densities up
+# to 0.15 nats and its per-row LL 9.1e-4 (relative) off a highest-precision
+# forward.  HIGHEST keeps float32, as the Pallas kernels do, and
+# chip_smoke.py phase (c) holds the forward to 1e-5 of that reference.  CPU
+# ignores the setting.  Every site reads it as ``layers.PRECISION`` when it
+# traces, so a reference can override it in one place.
+PRECISION = jax.lax.Precision.HIGHEST
+
 
 def log_einsum_exp(w: jax.Array, ln_left: jax.Array, ln_right: jax.Array,
                    impl: str = "xla") -> jax.Array:
@@ -51,7 +62,7 @@ def log_einsum_exp(w: jax.Array, ln_left: jax.Array, ln_right: jax.Array,
     ap = jnp.maximum(ap, NEG_INF)
     el = jnp.exp(ln_left - a)  # in (0, 1]
     er = jnp.exp(ln_right - ap)
-    s = jnp.einsum("lkij,bli,blj->blk", w, el, er)
+    s = jnp.einsum("lkij,bli,blj->blk", w, el, er, precision=PRECISION)
     return a + ap + jnp.log(s)
 
 

@@ -24,6 +24,8 @@ from __future__ import annotations
 import collections
 import dataclasses
 import statistics
+import sys
+import traceback
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro import obs
@@ -59,6 +61,7 @@ def run_training(
       on_step: observer called with (completed_step_count, state).
 
     Returns (final_state, stats) where stats["restarts"] counts recoveries.
+    Every retried exception is printed with its traceback to stderr.
     Raises RuntimeError once failures exceed ``cfg.max_restarts``.
     """
     state = init
@@ -81,6 +84,11 @@ def run_training(
         except Exception as e:  # noqa: BLE001 -- any step failure is a "node loss"
             restarts += 1
             failures.append(f"step {step}: {e!r}")
+            # a retried failure must stay visible: a compile refusal or an
+            # OOM looks like a node loss here, and a recovered run exits 0
+            print(f"[ft] step {step} failed (restart {restarts} of "
+                  f"{cfg.max_restarts}):", file=sys.stderr)
+            traceback.print_exception(e, file=sys.stderr)
             if restarts > cfg.max_restarts:
                 raise RuntimeError(
                     f"restart budget exhausted ({cfg.max_restarts} allowed, "

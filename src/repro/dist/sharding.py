@@ -30,8 +30,6 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro._jax_compat import ambient_mesh as _ambient_mesh
-
 Rules = Dict[str, Any]  # logical axis -> mesh axis | tuple of axes | None
 
 _state = threading.local()
@@ -148,16 +146,10 @@ def resolve_spec(
 
 
 def _mesh_in_scope():
-    mesh = _ambient_mesh()
-    if mesh is None:
-        return None
-    sizes = _mesh_axis_sizes(mesh)
-    total = 1
-    for s in sizes.values():
-        total *= s
-    if total <= 1:
-        return None
-    return mesh
+    """The mesh ``jax.set_mesh`` put in scope, or None when there is none
+    or it holds one device."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.size > 1 else None
 
 
 def constraint(x, axes: Sequence[Optional[str]]):

@@ -21,17 +21,21 @@ a plain ``BlockSpec`` over the second axis tiles the whole subtree:
   * grid = (L_out / s, B / B_t): each program computes ``s`` output cells of
     the final depth for one batch tile, walking all ``G`` depths locally.
     In block coordinates every depth is still the canonical split -- inputs
-    ``cur[:, :M/2]`` x ``cur[:, M/2:]`` -> outputs ``(B_t, M/2, s, K_out)``.
+    ``cur[m]`` x ``cur[m + M/2]`` -> output ``m`` for ``m < M/2``.
   * Each weight / input cell is read by EXACTLY ONE program (the trees are
     disjoint): fusion adds zero redundant HBM traffic, and shrinking ``s``
     shrinks the per-program working set proportionally, so the VMEM planner
     (``core.plan.plan_circuit``) can fuse arbitrarily wide depths by tiling
     the output cells instead of giving up.
-  * Per cell the contraction is the SAME ``(B_t, K^2) @ (K^2, K_out)`` MXU
-    dot as the per-layer kernel (identical operands, identical op), so the
-    fused forward is bit-identical to the per-layer Pallas path wherever the
-    padding contracts agree, and its gradients match autodiff of the chained
-    reference to float32 roundoff.
+  * Per cell the math is the per-layer kernel's own (``cell_fwd`` /
+    ``cell_bwd`` from ``log_einsum_exp.py``): the same stabilization and the
+    same ``(B_t, K^2) @ (K^2, K_out)`` MXU contraction.
+
+Inside a kernel the activations are Python lists of per-cell ``(B_t, K)``
+values: operands are read per cell by indexing REFS on their leading
+(cell) axes -- the cell-major ``(cells, B, K)`` layout of the per-layer
+kernel -- and every row selection is a static list lookup, so no loaded
+value is ever indexed or reshaped (Mosaic lowers neither).
 
 Padding contract (``ops.pad_group_for_lanes``): K is rounded up to a
 multiple of 16 exactly as in ``pad_for_lanes``; INTERIOR depths pad K_out to
@@ -59,23 +63,16 @@ PyJuice block-sparse thesis).  We deliberately do NOT use
 index maps, i.e. block-LEVEL indirection across the grid, while these
 gathers select rows WITHIN the single resident buffer block -- a static
 unroll is both simpler and exact.  The trade-off is one specialized program
-per distinct table set (fine: one circuit has a handful of segments) and,
-on real TPUs, constant-materialization of the tables (they are a few
-hundred ints; revisit with scalar prefetch only if Mosaic constant pools
-become a problem -- TPU validation is ROADMAP-gated).  Grid is batch-only:
-the row buffer is irregular, so the segment is not cell-tiled; the planner
-(``core.plan.gather_cost_bytes``) bounds run LENGTH instead of out_block.
-Interior depths keep K_out == K and outputs stay on the 16-multiple K lane
-(never widened to 128: all depths are non-final by construction).
+per distinct table set (fine: one circuit has a handful of segments).  Grid
+is batch-only: the row buffer is irregular, so the segment is not
+cell-tiled; the planner (``core.plan.gather_cost_bytes``) bounds run LENGTH
+instead of out_block.  Interior depths keep K_out == K and outputs stay on
+the 16-multiple K lane (never widened to 128: all depths are non-final by
+construction).
 
 Validated against autodiff of the chained XLA reference in interpret mode --
-see ``tests/test_grouped.py`` and ``tests/test_gather_grouped.py``.  Forward
-parity is bitwise; backward parity vs the per-layer path is bitwise on XLA
-and float32-ulp-level through these kernels (per-layer ops pad every K_out
-to 128 lanes while grouped interiors stay on the 16-pad, and gemm
-reductions over different padded lengths associate partial sums
-differently -- same values, different rounding; the per-depth math is
-identical).
+see ``tests/test_grouped.py`` and ``tests/test_gather_grouped.py`` -- and
+compiled for a described TPU v5e in ``tests/test_tpu_compile.py``.
 """
 
 from __future__ import annotations
@@ -86,100 +83,82 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.layers import NEG_INF
 from repro.kernels.dispatch import resolve_interpret
-
-# same stabilized-sum floor as the per-layer backward kernel (a NORMAL
-# float32: XLA flushes subnormals, and g / 0 on saturated rows must not inf)
-_S_FLOOR = 1e-30
-
-
-def _depth_fwd(w, cur):
-    """One canonical depth inside the kernel, in block coordinates.
-
-    w:   (M/2, s, K_out, K, K) weight block.
-    cur: (B_t, M, s, K) log-activations; left children are rows [0, M/2),
-         right children rows [M/2, M) (the canonical split).
-    Returns (B_t, M/2, s, K_out).
-    """
-    bb, m, s_, k = cur.shape
-    h = m // 2
-    ko = w.shape[2]
-    lnl, lnr = cur[:, :h], cur[:, h:]
-    # the per-layer kernel's exact stabilization, per (m, c) cell row
-    a = jnp.maximum(jnp.max(lnl, axis=-1, keepdims=True), NEG_INF)
-    ap = jnp.maximum(jnp.max(lnr, axis=-1, keepdims=True), NEG_INF)
-    el = jnp.exp(lnl - a)
-    er = jnp.exp(lnr - ap)
-    cols = []
-    for mi in range(h):
-        row = []
-        for ci in range(s_):
-            # outer product in VMEM, then the per-layer kernel's exact
-            # (B_t, K^2) @ (K^2, K_out) MXU contraction per cell
-            prod = (el[:, mi, ci, :, None] * er[:, mi, ci, None, :]).reshape(
-                bb, k * k
-            )
-            wmat = w[mi, ci].reshape(ko, k * k)
-            s = jnp.dot(prod, wmat.T, preferred_element_type=jnp.float32)
-            row.append(a[:, mi, ci] + ap[:, mi, ci] + jnp.log(s))
-        cols.append(jnp.stack(row, axis=1))  # (B_t, s, K_out)
-    return jnp.stack(cols, axis=1)  # (B_t, M/2, s, K_out)
+from repro.kernels.log_einsum_exp import (
+    S_FLOOR,
+    cell_bwd,
+    cell_fwd,
+    cell_major,
+    compiler_params,
+    expanders,
+    flat_weights,
+    pad_batch,
+)
 
 
-def _depth_bwd(w, cur, gout):
-    """Backward of one canonical depth, in block coordinates.
+def _zero(refs):
+    """Zero accumulator blocks on the first batch tile (batch tiles revisit
+    the same dW / dV block: batch is the innermost, sequential grid axis)."""
+    for r in refs:
+        r[...] = jnp.zeros(r.shape, r.dtype)
 
-    gout: (B_t, M/2, s, K_out) cotangent of this depth's outputs.
-    Returns (gw (M/2, s, K_out, K, K), gin (B_t, M, s, K)).
-    """
-    bb, m, s_, k = cur.shape
-    h = m // 2
-    ko = w.shape[2]
-    lnl, lnr = cur[:, :h], cur[:, h:]
-    a = jnp.maximum(jnp.max(lnl, axis=-1, keepdims=True), NEG_INF)
-    ap = jnp.maximum(jnp.max(lnr, axis=-1, keepdims=True), NEG_INF)
-    el = jnp.exp(lnl - a)
-    er = jnp.exp(lnr - ap)
-    gw_cols, gl_cols, gr_cols = [], [], []
-    for mi in range(h):
-        gw_row, gl_row, gr_row = [], [], []
-        for ci in range(s_):
-            eli, eri = el[:, mi, ci], er[:, mi, ci]  # (B_t, K)
-            prod = (eli[:, :, None] * eri[:, None, :]).reshape(bb, k * k)
-            wmat = w[mi, ci].reshape(ko, k * k)
-            # forward's stabilized sum, recomputed with the forward's exact
-            # contraction (same operands, same op -> bit-identical frame)
-            s = jnp.dot(prod, wmat.T, preferred_element_type=jnp.float32)
-            ginv = gout[:, mi, ci] / jnp.maximum(s, _S_FLOOR)  # (B_t, K_out)
-            gw_row.append(
-                jax.lax.dot_general(
-                    ginv, prod, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ).reshape(ko, k, k)
-            )
-            c = jnp.dot(ginv, wmat, preferred_element_type=jnp.float32)
-            c = c.reshape(bb, k, k)
-            gl_row.append(eli * jnp.sum(c * eri[:, None, :], axis=2))
-            gr_row.append(eri * jnp.sum(c * eli[:, :, None], axis=1))
-        gw_cols.append(jnp.stack(gw_row, axis=0))  # (s, K_out, K, K)
-        gl_cols.append(jnp.stack(gl_row, axis=1))  # (B_t, s, K)
-        gr_cols.append(jnp.stack(gr_row, axis=1))
-    gw = jnp.stack(gw_cols, axis=0)  # (M/2, s, K_out, K, K)
-    gin = jnp.concatenate(
-        [jnp.stack(gl_cols, axis=1), jnp.stack(gr_cols, axis=1)], axis=1
-    )  # (B_t, M, s, K)
-    return gw, gin
+
+def _each_cell(w_ref, body):
+    """Run ``body(mi, ci)`` over one canonical depth's (M/2, s) output cells
+    as a loop (one compiled cell body per depth, not one per cell)."""
+    h, s = w_ref.shape[:2]
+
+    def step(idx, carry):
+        body(idx // s, idx % s)
+        return carry
+
+    jax.lax.fori_loop(0, h * s, step, 0)
+
+
+def _depth_fwd(w_ref, src, dst, ex):
+    """One canonical depth in block coordinates: w_ref (M/2, s, K_out, K^2),
+    src (M, s, B_t, K) -> dst (M/2, s, B_t, K_out).  Left children are rows
+    [0, M/2) of ``src``, right children rows [M/2, M) (the canonical
+    split)."""
+    h = w_ref.shape[0]
+
+    def cell(mi, ci):
+        dst[mi, ci] = cell_fwd(
+            w_ref[mi, ci], src[mi, ci], src[mi + h, ci], ex
+        ).astype(dst.dtype)
+
+    _each_cell(w_ref, cell)
+
+
+def _depth_bwd(w_ref, src, gout, gw_ref, gin, ex):
+    """Backward of one canonical depth: accumulates dW into ``gw_ref`` and
+    writes the input cotangent ``gin`` (shaped like ``src``; every input
+    cell has exactly one consumer, so it is written once)."""
+    h = w_ref.shape[0]
+
+    def cell(mi, ci):
+        gw, gl, gr = cell_bwd(
+            w_ref[mi, ci], src[mi, ci], src[mi + h, ci], gout[mi, ci], ex
+        )
+        gw_ref[mi, ci] += gw.astype(gw_ref.dtype)
+        gin[mi, ci] = gl.astype(gin.dtype)
+        gin[mi + h, ci] = gr.astype(gin.dtype)
+
+    _each_cell(w_ref, cell)
 
 
 def _make_fwd_kernel(num_depths: int):
     def kernel(*refs):
-        w_refs, x_ref, o_ref = refs[:num_depths], refs[-2], refs[-1]
-        cur = x_ref[...]  # (B_t, 2^G, s, K)
+        w_refs = refs[:num_depths]
+        x_ref, o_ref = refs[num_depths], refs[num_depths + 1]
+        acts = refs[num_depths + 2:]  # VMEM scratch: interior depth outputs
+        ex = expanders(x_ref.shape[-1])
+        srcs, dsts = (x_ref,) + acts, acts + (o_ref,)
         for g in range(num_depths):
-            cur = _depth_fwd(w_refs[g][...], cur)
-        o_ref[...] = cur[:, 0].astype(o_ref.dtype)  # (B_t, s, K_out_final)
+            _depth_fwd(w_refs[g], srcs[g], dsts[g], ex)
 
     return kernel
 
@@ -189,42 +168,22 @@ def _make_bwd_kernel(num_depths: int):
         w_refs = refs[:num_depths]
         x_ref, g_ref = refs[num_depths], refs[num_depths + 1]
         gw_refs = refs[num_depths + 2: 2 * num_depths + 2]
-        gx_ref = refs[-1]
-        bi = pl.program_id(1)
+        gx_ref = refs[2 * num_depths + 2]
+        # VMEM scratch: interior depth outputs, then their cotangents
+        scratch = refs[2 * num_depths + 3:]
+        acts, cots = scratch[: num_depths - 1], scratch[num_depths - 1:]
+        ex = expanders(x_ref.shape[-1])
         # recompute every depth's activations in VMEM (residual-recompute:
         # nothing but the group inputs was saved)
-        acts = [x_ref[...]]
+        srcs = (x_ref,) + acts
         for g in range(num_depths - 1):
-            acts.append(_depth_fwd(w_refs[g][...], acts[-1]))
-        gcur = g_ref[...][:, None]  # (B_t, 1, s, K_out_final)
+            _depth_fwd(w_refs[g], srcs[g], acts[g], ex)
+        pl.when(pl.program_id(1) == 0)(lambda: _zero(gw_refs))
+        gins, gouts = (gx_ref,) + cots, cots + (g_ref,)
         for g in reversed(range(num_depths)):
-            gw_g, gcur = _depth_bwd(w_refs[g][...], acts[g], gcur)
-            gw_ref = gw_refs[g]
-
-            # batch tiles revisit the same dW block: init then accumulate
-            # (batch is the innermost, sequential grid axis)
-            @pl.when(bi == 0)
-            def _init(gw_ref=gw_ref, gw_g=gw_g):
-                gw_ref[...] = gw_g.astype(gw_ref.dtype)
-
-            @pl.when(bi > 0)
-            def _acc(gw_ref=gw_ref, gw_g=gw_g):
-                gw_ref[...] += gw_g.astype(gw_ref.dtype)
-
-        gx_ref[...] = gcur.astype(gx_ref.dtype)
+            _depth_bwd(w_refs[g], srcs[g], gouts[g], gw_refs[g], gins[g], ex)
 
     return kernel
-
-
-def _pad_batch(block_b, *arrays):
-    b = arrays[0].shape[0]
-    pad_b = (-b) % block_b
-    if not pad_b:
-        return arrays
-    return tuple(
-        jnp.concatenate([x, jnp.zeros((pad_b,) + x.shape[1:], x.dtype)], 0)
-        for x in arrays
-    )
 
 
 def _group_geometry(ws: Sequence[jax.Array], x: jax.Array):
@@ -251,6 +210,41 @@ def _group_geometry(ws: Sequence[jax.Array], x: jax.Array):
                 "outputs feed the next depth so K_out must equal K"
             )
     return g, l_out, k, ws[-1].shape[1]
+
+
+def _group_operands(ws, x, l_out, out_block, block_b):
+    """Cell-major operands and block specs for a canonical run.
+
+    The input is viewed as (2^G, L_out, B, K) and depth ``d``'s weights as
+    (2^(G-1-d), L_out, K_out_d, K^2); program (ti, bi) reads the subtrees of
+    output cells [ti*s, (ti+1)*s) for batch tile ``bi``.  Returns
+    (w_t, x_t, w_specs, act_spec, scratch) where ``act_spec(m, lanes)``
+    blocks an (m, L_out, B, lanes) activation and ``scratch`` holds one VMEM
+    buffer per interior depth output.
+    """
+    g = len(ws)
+    k = x.shape[-1]
+    s = out_block
+    x_t = cell_major(x).reshape(2 ** g, l_out, x.shape[0], k)
+    w_t = [
+        flat_weights(w).reshape(2 ** (g - 1 - d), l_out, w.shape[1], k * k)
+        for d, w in enumerate(ws)
+    ]
+    w_specs = [
+        pl.BlockSpec((w.shape[0], s) + w.shape[2:],
+                     lambda ti, bi: (0, ti, 0, 0))
+        for w in w_t
+    ]
+
+    def act_spec(m, lanes):
+        return pl.BlockSpec((m, s, block_b, lanes),
+                            lambda ti, bi: (0, ti, bi, 0))
+
+    scratch = [
+        pltpu.VMEM((2 ** (g - 1 - d), s, block_b, k), jnp.float32)
+        for d in range(g - 1)
+    ]
+    return w_t, x_t, w_specs, act_spec, scratch
 
 
 @functools.partial(
@@ -286,33 +280,23 @@ def grouped_log_einsum_exp_pallas(
         raise ValueError(f"out_block {out_block} does not divide L_out {l_out}")
     b = x.shape[0]
     block_b = min(block_b, b)
-    (x,) = _pad_batch(block_b, x)
+    (x,) = pad_batch(block_b, x)
     bp = x.shape[0]
-    s = out_block
-    grid = (l_out // s, bp // block_b)
-    x_r = x.reshape(bp, 2 ** g, l_out, k)
-    w_r = [
-        w.reshape(2 ** (g - 1 - d), l_out, w.shape[1], k, k)
-        for d, w in enumerate(ws)
-    ]
-    in_specs = [
-        pl.BlockSpec(
-            (2 ** (g - 1 - d), s, w_r[d].shape[2], k, k),
-            lambda ti, bi: (0, ti, 0, 0, 0),
-        )
-        for d in range(g)
-    ] + [pl.BlockSpec((block_b, 2 ** g, s, k), lambda ti, bi: (bi, 0, ti, 0))]
+    w_t, x_t, w_specs, act_spec, scratch = _group_operands(
+        ws, x, l_out, out_block, block_b
+    )
     out = pl.pallas_call(
         _make_fwd_kernel(g),
-        out_shape=jax.ShapeDtypeStruct((bp, l_out, k_final), jnp.float32),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (block_b, s, k_final), lambda ti, bi: (bi, ti, 0)
-        ),
+        out_shape=jax.ShapeDtypeStruct((1, l_out, bp, k_final), jnp.float32),
+        grid=(l_out // out_block, bp // block_b),
+        in_specs=w_specs + [act_spec(2 ** g, k)],
+        out_specs=act_spec(1, k_final),
+        scratch_shapes=scratch,
+        compiler_params=compiler_params("parallel", "parallel"),
         interpret=interpret,
-    )(*w_r, x_r)
-    return out[:b] if bp != b else out
+        name="grouped_log_einsum_exp_fwd",
+    )(*w_t, x_t)
+    return cell_major(out[0])[:b]
 
 
 @functools.partial(
@@ -342,201 +326,104 @@ def grouped_log_einsum_exp_bwd_pallas(
         raise ValueError(f"out_block {out_block} does not divide L_out {l_out}")
     b = x.shape[0]
     block_b = min(block_b, b)
-    x, g_out = _pad_batch(block_b, x, g_out)
+    x, g_out = pad_batch(block_b, x, g_out)
     bp = x.shape[0]
-    s = out_block
-    grid = (l_out // s, bp // block_b)
-    x_r = x.reshape(bp, 2 ** g, l_out, k)
-    w_r = [
-        w.reshape(2 ** (g - 1 - d), l_out, w.shape[1], k, k)
-        for d, w in enumerate(ws)
-    ]
-    in_specs = [
-        pl.BlockSpec(
-            (2 ** (g - 1 - d), s, w_r[d].shape[2], k, k),
-            lambda ti, bi: (0, ti, 0, 0, 0),
-        )
-        for d in range(g)
-    ] + [
-        pl.BlockSpec((block_b, 2 ** g, s, k), lambda ti, bi: (bi, 0, ti, 0)),
-        pl.BlockSpec((block_b, s, k_final), lambda ti, bi: (bi, ti, 0)),
-    ]
-    # dW blocks are (M/2, s, K_out, K, K) in (m, c)-major layout: block
-    # index depends on ti only, so batch tiles (innermost axis) revisit and
-    # accumulate into the same block
-    gw_shapes = tuple(
-        jax.ShapeDtypeStruct(
-            (2 ** (g - 1 - d), l_out, w_r[d].shape[2], k, k), jnp.float32
-        )
-        for d in range(g)
+    w_t, x_t, w_specs, act_spec, scratch = _group_operands(
+        ws, x, l_out, out_block, block_b
     )
-    gw_specs = tuple(
-        pl.BlockSpec(
-            (2 ** (g - 1 - d), s, w_r[d].shape[2], k, k),
-            lambda ti, bi: (0, ti, 0, 0, 0),
-        )
-        for d in range(g)
-    )
+    x_spec = act_spec(2 ** g, k)
+    # dW blocks index on ti only, so batch tiles (innermost axis) revisit
+    # and accumulate into the same block
     outs = pl.pallas_call(
         _make_bwd_kernel(g),
-        out_shape=gw_shapes
-        + (jax.ShapeDtypeStruct((bp, 2 ** g, l_out, k), jnp.float32),),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=gw_specs
-        + (pl.BlockSpec((block_b, 2 ** g, s, k), lambda ti, bi: (bi, 0, ti, 0)),),
+        out_shape=tuple(
+            jax.ShapeDtypeStruct(w.shape, jnp.float32) for w in w_t
+        ) + (jax.ShapeDtypeStruct(x_t.shape, jnp.float32),),
+        grid=(l_out // out_block, bp // block_b),
+        in_specs=w_specs + [x_spec, act_spec(1, k_final)],
+        out_specs=tuple(w_specs) + (x_spec,),
+        scratch_shapes=scratch + scratch,  # activations, then cotangents
+        compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
-    )(*w_r, x_r, g_out)
-    gws = tuple(
-        gw.reshape(w.shape[0], w.shape[1], k, k) for gw, w in zip(outs[:g], ws)
-    )
-    gx = outs[g].reshape(bp, l_out * 2 ** g, k)
-    return gws, gx[:b] if bp != b else gx
+        name="grouped_log_einsum_exp_bwd",
+    )(*w_t, x_t, cell_major(g_out)[None])
+    gws = tuple(gw.reshape(w.shape) for gw, w in zip(outs[:g], ws))
+    gx = cell_major(outs[g].reshape(l_out * 2 ** g, bp, k))
+    return gws, gx[:b]
 
 
 # ---------------------------------------------------------------------------
 # gather-grouped kernels: static-table topology (PD), mixing in-kernel
 # ---------------------------------------------------------------------------
-def _gather_depth_fwd(w, lnl, lnr):
-    """One gather depth inside the kernel: flat per-cell operands.
-
-    w:         (L, K_out, K, K) weight block.
-    lnl / lnr: (B_t, L, K) gathered log-activations.
-    Returns (B_t, L, K_out) -- the per-layer kernel's exact stabilization
-    and (B_t, K^2) @ (K^2, K_out) MXU contraction, per cell.
-    """
-    bb, l, k = lnl.shape
-    ko = w.shape[1]
-    a = jnp.maximum(jnp.max(lnl, axis=-1, keepdims=True), NEG_INF)
-    ap = jnp.maximum(jnp.max(lnr, axis=-1, keepdims=True), NEG_INF)
-    el = jnp.exp(lnl - a)
-    er = jnp.exp(lnr - ap)
-    outs = []
-    for li in range(l):
-        prod = (el[:, li, :, None] * er[:, li, None, :]).reshape(bb, k * k)
-        wmat = w[li].reshape(ko, k * k)
-        s = jnp.dot(prod, wmat.T, preferred_element_type=jnp.float32)
-        outs.append(a[:, li] + ap[:, li] + jnp.log(s))
-    return jnp.stack(outs, axis=1)
-
-
-def _gather_depth_bwd(w, lnl, lnr, gout):
-    """Backward of one gather depth (the per-layer backward's exact math).
-
-    gout: (B_t, L, K_out) cotangent of this depth's einsum outputs.
-    Returns (gw (L, K_out, K, K), gl (B_t, L, K), gr (B_t, L, K)).
-    """
-    bb, l, k = lnl.shape
-    ko = w.shape[1]
-    a = jnp.maximum(jnp.max(lnl, axis=-1, keepdims=True), NEG_INF)
-    ap = jnp.maximum(jnp.max(lnr, axis=-1, keepdims=True), NEG_INF)
-    el = jnp.exp(lnl - a)
-    er = jnp.exp(lnr - ap)
-    gw_rows, gl_rows, gr_rows = [], [], []
-    for li in range(l):
-        eli, eri = el[:, li], er[:, li]  # (B_t, K)
-        prod = (eli[:, :, None] * eri[:, None, :]).reshape(bb, k * k)
-        wmat = w[li].reshape(ko, k * k)
-        # forward's stabilized sum, recomputed bit-exactly
-        s = jnp.dot(prod, wmat.T, preferred_element_type=jnp.float32)
-        ginv = gout[:, li] / jnp.maximum(s, _S_FLOOR)  # (B_t, K_out)
-        gw_rows.append(
-            jax.lax.dot_general(
-                ginv, prod, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ).reshape(ko, k, k)
-        )
-        c = jnp.dot(ginv, wmat, preferred_element_type=jnp.float32)
-        c = c.reshape(bb, k, k)
-        gl_rows.append(eli * jnp.sum(c * eri[:, None, :], axis=2))
-        gr_rows.append(eri * jnp.sum(c * eli[:, :, None], axis=1))
-    return (
-        jnp.stack(gw_rows, axis=0),
-        jnp.stack(gl_rows, axis=1),
-        jnp.stack(gr_rows, axis=1),
-    )
-
-
-def _gather_mix_frame(v, s, child, mask):
-    """``core.layers._log_mix_exp_frame`` replicated in-kernel on statically
-    gathered children: (masked ln, clamped max, exp'd inputs, stabilized
+def _mix_frame(v_ref, mi, kids, mask_row):
+    """``core.layers._log_mix_exp_frame`` for mixing node ``mi`` on its
+    statically gathered children: (clamped max, exp'd inputs, stabilized
     sum).  The mask is applied by STATIC selection (padded children become
     NEG_INF rows at trace time -- Pallas kernels cannot capture array
     constants), which selects exactly the values ``jnp.where(mask > 0, ...)``
-    selects; every traced op then matches the XLA frame expression for
-    expression, so mixing rows are bitwise-identical.
+    selects.
 
-    v: (M, C, K); s: (B_t, L, K) this depth's einsum rows; child / mask:
-    STATIC (M, C) nested int tuples (local einsum indices, 0/1 flags).
+    v_ref: (M, C, K); kids: C einsum rows (B_t, K); mask_row: C static 0/1.
     """
-    bb, _, k = s.shape
-    neg = jnp.full((bb, k), NEG_INF, dtype=s.dtype)
-    lnm = jnp.stack(
-        [
-            jnp.stack(
-                [
-                    s[:, c, :] if mask[mi][ci] else neg
-                    for ci, c in enumerate(row)
-                ],
-                axis=1,
-            )
-            for mi, row in enumerate(child)
-        ],
-        axis=1,
-    )  # (B_t, M, C, K)
-    a = jnp.maximum(jnp.max(lnm, axis=2, keepdims=True), NEG_INF)
-    e = jnp.exp(lnm - a)
-    ssum = jnp.sum(v[None] * e, axis=2)  # (B_t, M, K)
+    lnm = [
+        kid if m else jnp.full(kid.shape, NEG_INF, kid.dtype)
+        for kid, m in zip(kids, mask_row)
+    ]
+    a = functools.reduce(jnp.maximum, lnm)
+    a = jnp.maximum(a, NEG_INF)
+    e = [jnp.exp(ln - a) for ln in lnm]
+    ssum = functools.reduce(
+        jnp.add, [v_ref[mi, ci:ci + 1, :] * ec for ci, ec in enumerate(e)]
+    )
     return a, e, ssum
 
 
-def _gather_fwd_sweep(tables, w_blocks, v_blocks, x):
-    """The shared forward walk over an in-VMEM row list: returns
-    (rows, new_rows, frames) where frames[t] = (lnl, lnr, s, e_base, m_base)
-    for the backward's residual recompute."""
-    r_in = tables.num_in_rows
-    rows = [x[:, r, :] for r in range(r_in)]
-    new_rows = []
-    frames = []
+def _gather_fwd_sweep(tables, w_refs, v_refs, rows, ex):
+    """The shared forward walk over an in-VMEM row list.
+
+    ``rows`` starts as the segment's input rows; every depth appends its
+    einsum rows then its mixing rows (global row order).  Returns
+    (rows, e_bases) where e_bases[t] is the list index of depth ``t``'s
+    first einsum row, for the backward's residual recompute.
+    """
+    rows = list(rows)
+    e_bases = []
     vi = 0
     for t in range(tables.num_depths):
-        lnl = jnp.stack([rows[r] for r in tables.left[t]], axis=1)
-        lnr = jnp.stack([rows[r] for r in tables.right[t]], axis=1)
-        s = _gather_depth_fwd(w_blocks[t], lnl, lnr)  # (B_t, L, K)
-        e_base = len(rows)
-        for li in range(s.shape[1]):
-            rows.append(s[:, li, :])
-            new_rows.append(s[:, li, :])
-        m_base = None
+        left, right = tables.left[t], tables.right[t]
+        s = [
+            cell_fwd(w_refs[t][li], rows[left[li]], rows[right[li]], ex)
+            for li in range(len(left))
+        ]
+        e_bases.append(len(rows))
+        rows.extend(s)
         if tables.mix_child[t] is not None:
-            a, _, ssum = _gather_mix_frame(
-                v_blocks[vi], s, tables.mix_child[t], tables.mix_mask[t]
-            )
+            for mi, (child, mask) in enumerate(
+                zip(tables.mix_child[t], tables.mix_mask[t])
+            ):
+                a, _, ssum = _mix_frame(
+                    v_refs[vi], mi, [s[c] for c in child], mask
+                )
+                rows.append(a + jnp.log(ssum))
             vi += 1
-            m = a[:, :, 0, :] + jnp.log(ssum)  # (B_t, M, K)
-            m_base = len(rows)
-            for mi in range(m.shape[1]):
-                rows.append(m[:, mi, :])
-                new_rows.append(m[:, mi, :])
-        frames.append((lnl, lnr, s, e_base, m_base))
-    return rows, new_rows, frames
+    return rows, e_bases
 
 
 def _make_gather_fwd_kernel(tables):
     d_total = tables.num_depths
     n_mix = tables.num_mix_depths
+    r_in = tables.num_in_rows
 
     def kernel(*refs):
         w_refs = refs[:d_total]
         v_refs = refs[d_total: d_total + n_mix]
         x_ref, o_ref = refs[-2], refs[-1]
-        _, new_rows, _ = _gather_fwd_sweep(
-            tables,
-            [w[...] for w in w_refs],
-            [v[...] for v in v_refs],
-            x_ref[...],
+        ex = expanders(x_ref.shape[-1])
+        rows, _ = _gather_fwd_sweep(
+            tables, w_refs, v_refs, [x_ref[r] for r in range(r_in)], ex
         )
-        o_ref[...] = jnp.stack(new_rows, axis=1).astype(o_ref.dtype)
+        for idx, row in enumerate(rows[r_in:]):
+            o_ref[idx] = row.astype(o_ref.dtype)
 
     return kernel
 
@@ -554,87 +441,59 @@ def _make_gather_bwd_kernel(tables):
         gw_refs = refs[d_total + n_mix + 2: 2 * d_total + n_mix + 2]
         gv_refs = refs[2 * d_total + n_mix + 2: 2 * d_total + 2 * n_mix + 2]
         gx_ref = refs[-1]
-        bi = pl.program_id(0)
-
-        w_blocks = [w[...] for w in w_refs]
-        v_blocks = [v[...] for v in v_refs]
-        g = g_ref[...]  # (B_t, r_new, K)
-        # residual-recompute: re-derive every row + every depth's frame
-        rows, _, frames = _gather_fwd_sweep(
-            tables, w_blocks, v_blocks, x_ref[...]
+        ex = expanders(x_ref.shape[-1])
+        # residual-recompute: re-derive every row from the primals
+        rows, e_bases = _gather_fwd_sweep(
+            tables, w_refs, v_refs, [x_ref[r] for r in range(r_in)], ex
+        )
+        pl.when(pl.program_id(0) == 0)(
+            lambda: _zero(tuple(gw_refs) + tuple(gv_refs))
         )
         zero = jnp.zeros_like(rows[0])
         cot = [zero] * r_in + [
-            g[:, idx, :] for idx in range(len(rows) - r_in)
+            g_ref[idx] for idx in range(len(rows) - r_in)
         ]
         vi = n_mix
         for t in reversed(range(d_total)):
-            lnl, lnr, s, e_base, m_base = frames[t]
+            e_base = e_bases[t]
+            n_e = len(tables.left[t])
+            s = rows[e_base: e_base + n_e]
             # mixing backward FIRST: its gradient lands on this depth's
             # einsum rows before their own backward runs
             if tables.mix_child[t] is not None:
                 vi -= 1
-                v = v_blocks[vi]
-                child = tables.mix_child[t]
-                mask = tables.mix_mask[t]
-                gm = jnp.stack(
-                    [cot[m_base + mi] for mi in range(len(child))], axis=1
-                )  # (B_t, M, K)
-                _, e, ssum = _gather_mix_frame(v, s, child, mask)
-                ginv = gm / jnp.maximum(ssum, _S_FLOOR)
-                # static masking (see _gather_mix_frame): masked children
-                # contribute exact zeros to dV and nothing to the scatter
-                gv_rows = []
-                for mi, row in enumerate(child):
-                    gv_cols = []
-                    for ci, c in enumerate(row):
-                        if mask[mi][ci]:
-                            ge = ginv[:, mi, :] * e[:, mi, ci, :]
-                            gv_cols.append(jnp.sum(ge, axis=0))
-                            cot[e_base + c] = (
-                                cot[e_base + c] + ge * v[mi, ci][None]
-                            )
-                        else:
-                            gv_cols.append(jnp.zeros_like(v[mi, ci]))
-                    gv_rows.append(jnp.stack(gv_cols, axis=0))
-                gv_t = jnp.stack(gv_rows, axis=0)  # (M, C, K)
-                gv_ref = gv_refs[vi]
-
-                @pl.when(bi == 0)
-                def _init_v(gv_ref=gv_ref, gv_t=gv_t):
-                    gv_ref[...] = gv_t.astype(gv_ref.dtype)
-
-                @pl.when(bi > 0)
-                def _acc_v(gv_ref=gv_ref, gv_t=gv_t):
-                    gv_ref[...] += gv_t.astype(gv_ref.dtype)
-
-            gs = jnp.stack(
-                [cot[e_base + li] for li in range(len(tables.left[t]))],
-                axis=1,
-            )
-            gw_t, gl, gr = _gather_depth_bwd(w_blocks[t], lnl, lnr, gs)
-            # scatter order (right vs left) is numerically irrelevant: a
-            # row hit by both sides accumulates two terms on top of its
-            # existing cotangent, and measured diffs vs the per-layer path
-            # are identical under either order -- the residual float32-ulp
-            # gap comes from gemm reduction association under different
-            # padded lane lengths (see gather_grouped docstring), not from
-            # scatter ordering
-            for li, r in enumerate(tables.right[t]):
-                cot[r] = cot[r] + gr[:, li, :]
-            for li, r in enumerate(tables.left[t]):
-                cot[r] = cot[r] + gl[:, li, :]
-            gw_ref = gw_refs[t]
-
-            @pl.when(bi == 0)
-            def _init_w(gw_ref=gw_ref, gw_t=gw_t):
-                gw_ref[...] = gw_t.astype(gw_ref.dtype)
-
-            @pl.when(bi > 0)
-            def _acc_w(gw_ref=gw_ref, gw_t=gw_t):
-                gw_ref[...] += gw_t.astype(gw_ref.dtype)
-
-        gx_ref[...] = jnp.stack(cot[:r_in], axis=1).astype(gx_ref.dtype)
+                v_ref, gv_ref = v_refs[vi], gv_refs[vi]
+                m_base = e_base + n_e
+                for mi, (child, mask) in enumerate(
+                    zip(tables.mix_child[t], tables.mix_mask[t])
+                ):
+                    _, e, ssum = _mix_frame(
+                        v_ref, mi, [s[c] for c in child], mask
+                    )
+                    ginv = cot[m_base + mi] / jnp.maximum(ssum, S_FLOOR)
+                    # static masking (see _mix_frame): masked children
+                    # contribute exact zeros to dV and nothing to the scatter
+                    for ci, c in enumerate(child):
+                        if not mask[ci]:
+                            continue
+                        ge = ginv * e[ci]
+                        gv_ref[mi, ci:ci + 1, :] += jnp.sum(
+                            ge, axis=0, keepdims=True
+                        )
+                        cot[e_base + c] = (
+                            cot[e_base + c] + ge * v_ref[mi, ci:ci + 1, :]
+                        )
+            for li, (lr, rr) in enumerate(
+                zip(tables.left[t], tables.right[t])
+            ):
+                gw, gl, gr = cell_bwd(
+                    w_refs[t][li], rows[lr], rows[rr], cot[e_base + li], ex
+                )
+                gw_refs[t][li] += gw.astype(gw_refs[t].dtype)
+                cot[rr] = cot[rr] + gr
+                cot[lr] = cot[lr] + gl
+        for r in range(r_in):
+            gx_ref[r] = cot[r].astype(gx_ref.dtype)
 
     return kernel
 
@@ -675,6 +534,16 @@ def _gather_geometry(tables, ws, vs, x):
     return tables.num_new_rows, k
 
 
+def _whole(shape):
+    """Block spec of an operand every program reads whole (weights)."""
+    return pl.BlockSpec(shape, lambda bi: (0,) * len(shape))
+
+
+def _batch_tile(rows, block_b, k):
+    """Block spec of a cell-major (rows, B, K) operand tiled over batch."""
+    return pl.BlockSpec((rows, block_b, k), lambda bi: (0, bi, 0))
+
+
 @functools.partial(
     jax.jit, static_argnames=("tables", "block_b", "interpret")
 )
@@ -706,24 +575,22 @@ def gather_grouped_log_einsum_exp_pallas(
     r_new, k = _gather_geometry(tables, ws, vs, x)
     b = x.shape[0]
     block_b = min(block_b, b)
-    (x,) = _pad_batch(block_b, x)
+    (x,) = pad_batch(block_b, x)
     bp = x.shape[0]
-    grid = (bp // block_b,)
-    r_in = tables.num_in_rows
-    in_specs = (
-        [pl.BlockSpec(w.shape, lambda bi: (0, 0, 0, 0)) for w in ws]
-        + [pl.BlockSpec(v.shape, lambda bi: (0, 0, 0)) for v in vs]
-        + [pl.BlockSpec((block_b, r_in, k), lambda bi: (bi, 0, 0))]
-    )
+    w_t = [flat_weights(w) for w in ws]
     out = pl.pallas_call(
         _make_gather_fwd_kernel(tables),
-        out_shape=jax.ShapeDtypeStruct((bp, r_new, k), jnp.float32),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_b, r_new, k), lambda bi: (bi, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((r_new, bp, k), jnp.float32),
+        grid=(bp // block_b,),
+        in_specs=[_whole(w.shape) for w in w_t]
+        + [_whole(v.shape) for v in vs]
+        + [_batch_tile(tables.num_in_rows, block_b, k)],
+        out_specs=_batch_tile(r_new, block_b, k),
+        compiler_params=compiler_params("parallel"),
         interpret=interpret,
-    )(*ws, *vs, x)
-    return out[:b] if bp != b else out
+        name="gather_grouped_log_einsum_exp_fwd",
+    )(*w_t, *vs, cell_major(x))
+    return cell_major(out)[:b]
 
 
 @functools.partial(
@@ -741,8 +608,7 @@ def gather_grouped_log_einsum_exp_bwd_pallas(
     """Fused gather-topology backward: dW per depth, dV per mixing depth and
     the input-buffer cotangent, one launch (residual-recompute: the forward
     rows and every stabilized frame are re-derived in VMEM from the primals;
-    dW/dV accumulate across batch tiles via ``pl.when`` on the sequential
-    batch grid axis).
+    dW/dV accumulate across batch tiles on the sequential batch grid axis).
 
     Returns: (gws tuple matching ``ws``, gvs tuple matching ``vs``,
     gx (B, r_in, K)).
@@ -751,41 +617,27 @@ def gather_grouped_log_einsum_exp_bwd_pallas(
     r_new, k = _gather_geometry(tables, ws, vs, x)
     b = x.shape[0]
     block_b = min(block_b, b)
-    x, g_out = _pad_batch(block_b, x, g_out)
+    x, g_out = pad_batch(block_b, x, g_out)
     bp = x.shape[0]
-    grid = (bp // block_b,)
     r_in = tables.num_in_rows
-    d_total = tables.num_depths
-    in_specs = (
-        [pl.BlockSpec(w.shape, lambda bi: (0, 0, 0, 0)) for w in ws]
-        + [pl.BlockSpec(v.shape, lambda bi: (0, 0, 0)) for v in vs]
-        + [
-            pl.BlockSpec((block_b, r_in, k), lambda bi: (bi, 0, 0)),
-            pl.BlockSpec((block_b, r_new, k), lambda bi: (bi, 0, 0)),
-        ]
-    )
+    w_t = [flat_weights(w) for w in ws]
     # dW / dV blocks ignore the batch grid index: every batch tile revisits
-    # the same block and accumulates (batch is the only -- hence innermost,
-    # sequential -- grid axis)
-    out_shape = (
-        tuple(jax.ShapeDtypeStruct(w.shape, jnp.float32) for w in ws)
-        + tuple(jax.ShapeDtypeStruct(v.shape, jnp.float32) for v in vs)
-        + (jax.ShapeDtypeStruct((bp, r_in, k), jnp.float32),)
-    )
-    out_specs = (
-        tuple(pl.BlockSpec(w.shape, lambda bi: (0, 0, 0, 0)) for w in ws)
-        + tuple(pl.BlockSpec(v.shape, lambda bi: (0, 0, 0)) for v in vs)
-        + (pl.BlockSpec((block_b, r_in, k), lambda bi: (bi, 0, 0)),)
-    )
+    # the same block and accumulates
+    acc_specs = [_whole(w.shape) for w in w_t] + [_whole(v.shape) for v in vs]
     outs = pl.pallas_call(
         _make_gather_bwd_kernel(tables),
-        out_shape=out_shape,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
+        out_shape=tuple(
+            jax.ShapeDtypeStruct(a.shape, jnp.float32) for a in (*w_t, *vs)
+        ) + (jax.ShapeDtypeStruct((r_in, bp, k), jnp.float32),),
+        grid=(bp // block_b,),
+        in_specs=acc_specs
+        + [_batch_tile(r_in, block_b, k), _batch_tile(r_new, block_b, k)],
+        out_specs=tuple(acc_specs) + (_batch_tile(r_in, block_b, k),),
+        compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
-    )(*ws, *vs, x, g_out)
-    gws = tuple(outs[:d_total])
+        name="gather_grouped_log_einsum_exp_bwd",
+    )(*w_t, *vs, cell_major(x), cell_major(g_out))
+    d_total = len(ws)
+    gws = tuple(gw.reshape(w.shape) for gw, w in zip(outs[:d_total], ws))
     gvs = tuple(outs[d_total: d_total + len(vs)])
-    gx = outs[-1]
-    return gws, gvs, gx[:b] if bp != b else gx
+    return gws, gvs, cell_major(outs[-1])[:b]

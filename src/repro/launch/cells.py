@@ -31,7 +31,9 @@ def input_specs(cfg: EinetConfig, shape_spec=None) -> Dict[str, Any]:
     return {"x": _sds((cfg.batch_size, d), jnp.float32)}
 
 
-def build_einet(cfg: EinetConfig) -> EiNet:
+def build_einet(cfg: EinetConfig, impl: str = "xla") -> EiNet:
+    """The registered config's EiNet; ``impl`` picks the log-einsum-exp
+    path ("xla" or the "pallas" kernels)."""
     if cfg.structure == "pd":
         graph = poon_domingos(
             cfg.height, cfg.width, cfg.delta, cfg.num_channels, cfg.pd_axes
@@ -50,7 +52,7 @@ def build_einet(cfg: EinetConfig) -> EiNet:
             f"{cfg.name}: unsupported leaf family {cfg.exponential_family!r}"
         )
     return EiNet(graph, num_sums=cfg.num_sums, num_classes=cfg.num_classes,
-                 exponential_family=ef)
+                 exponential_family=ef, impl=impl)
 
 
 def lower_einet_cell(cfg: EinetConfig, mesh, multi_pod: bool):

@@ -119,4 +119,7 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     main()

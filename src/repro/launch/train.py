@@ -118,7 +118,8 @@ def main():
                     help="tiny built-in arch, few steps, health telemetry "
                          "on (CI trace-smoke profile)")
     ap.add_argument("--steps", type=int, default=None)
-    ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--batch", type=int, default=None,
+                    help="global batch (default: the config's batch_size)")
     ap.add_argument("--model-parallel", type=int, default=1)
     ap.add_argument("--ckpt-dir", default="artifacts/ckpt")
     ap.add_argument("--checkpoint-every", type=int, default=25)
@@ -174,6 +175,7 @@ def main():
         args.arch = args.arch or cfg.name
     else:
         cfg = get_config(args.arch)
+    global_batch = args.batch or cfg.batch_size
     mesh = make_mesh_for(model_parallel=args.model_parallel)
     rules = shlib.default_rules(multi_pod=False, fsdp=False)
     mgr = CheckpointManager(
@@ -204,13 +206,13 @@ def main():
             )
             if args.mixture_assign == "hard":
                 params, loader, km = mx.prepare_mixture_training(
-                    model, data, seed=0, global_batch=args.batch * 32,
+                    model, data, seed=0, global_batch=global_batch,
                 )
                 print(f"[train] k-means clusters: {km.counts.tolist()} "
                       f"(inertia {km.inertia:.4f})")
             else:
                 params = model.init(jax.random.PRNGKey(0))
-                loader = einet_loader(data, args.batch * 32)
+                loader = einet_loader(data, global_batch)
             step_jit = mx.make_mixture_em_step(model, mcfg)
 
             def step_fn(state, batch):
@@ -233,7 +235,7 @@ def main():
             params = model.init(jax.random.PRNGKey(0))
             data = einet_train_data(cfg, args.dataset, args.data_dir)
             loader = einet_loader(
-                data, args.batch * 32,
+                data, global_batch,
                 num_shards=jax.process_count(), shard_id=jax.process_index(),
             )
             # the whole EM update -- scan-accumulated E-step, M-step, blend --
@@ -323,4 +325,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     main()

@@ -48,6 +48,7 @@ from repro.core.em import (
     leaf_scatter,
     m_step,
 )
+from repro.core import layers
 from repro.data.pipeline import ShardedLoader
 from repro.mixture.cluster import cluster_order
 from repro.mixture.model import EiNetMixture, _W_FLOOR
@@ -130,7 +131,8 @@ def mixture_em_statistics(
 
     def leaf_stats_one(g_leaf_c):
         g_pairs = g_leaf_c[:, ls.pair_leaf, :]  # (B, P, K)
-        s_phi_pairs = jnp.einsum("bpk,bpt->pkt", g_pairs, t_pairs)
+        s_phi_pairs = jnp.einsum("bpk,bpt->pkt", g_pairs, t_pairs,
+                                 precision=layers.PRECISION)
         s_den_pairs = jnp.sum(g_pairs, axis=0)
         return leaf_scatter(model, s_phi_pairs, s_den_pairs)
 

@@ -342,7 +342,7 @@ def make_sharded_em_step(
         out_specs=(P(), P()),
         # psum'd statistics make the outputs replicated; rep-tracking can't
         # see through the update's tree_map, so assert it ourselves (tests)
-        check_rep=False,
+        check_vma=False,
     )
     donate_flag = _resolve_donate(cfg.donate)
     donate = (0,) if donate_flag else ()

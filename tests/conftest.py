@@ -6,22 +6,7 @@ process must keep seeing 1 device).  They take minutes, so the tier-1 loop
 skips them; opt in with ``--runslow`` (CI runs them as a separate job).
 """
 
-import importlib.util
-import os
-import sys
-
 import pytest
-
-try:  # the container may not ship hypothesis; tests fall back to a
-    import hypothesis  # noqa: F401  deterministic mini-sampler (same API slice)
-except ImportError:
-    _spec = importlib.util.spec_from_file_location(
-        "hypothesis",
-        os.path.join(os.path.dirname(__file__), "_hypothesis_fallback.py"),
-    )
-    _mod = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_mod)
-    sys.modules["hypothesis"] = _mod
 
 
 @pytest.fixture

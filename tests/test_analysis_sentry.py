@@ -52,7 +52,7 @@ def test_weak_typed_prior_detected(small_net, compile_sentry):
     # the pre-PR-3 construction: no dtype= -> weak_type=True
     params["class_prior"] = jnp.full(
         (net.num_classes,), 1.0 / net.num_classes)
-    assert jax.core.get_aval(params["class_prior"]).weak_type
+    assert jax.typeof(params["class_prior"]).weak_type
     x = jnp.asarray(np.random.RandomState(0).randn(16, net.num_vars),
                     jnp.float32)
     raw = make_em_step(net, TrainConfig(), registry=ProgramRegistry())

@@ -64,10 +64,10 @@ def test_tree_mismatch_rejected(tmp_path):
 
 
 # ------------------------------------------------------------ fault tolerance
-def test_run_training_with_failures(tmp_path):
+def test_run_training_with_failures(tmp_path, capsys):
     """Injected crashes at steps 7 and 13 must not change the final result:
     restart from the last checkpoint reproduces the exact state (stateless
-    data + deterministic step)."""
+    data + deterministic step).  Each retried exception is printed."""
     mgr = CheckpointManager(str(tmp_path / "a"), async_write=False)
 
     def step_fn(state, batch):
@@ -90,6 +90,9 @@ def test_run_training_with_failures(tmp_path):
         fail_injector=injector,
     )
     assert stats["restarts"] == 2
+    err = capsys.readouterr().err
+    assert "simulated node failure at 7" in err
+    assert "simulated node failure at 13" in err
     # reference run without failures
     mgr2 = CheckpointManager(str(tmp_path / "b"), async_write=False)
     ref, _ = ft.run_training(step_fn, init, batch_at, mgr2, num_steps=20,

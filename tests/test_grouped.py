@@ -4,10 +4,19 @@ path, plan/fallback behaviour, and the kernels' dispatch contract.
 The tentpole contract this file pins:
 
   * grouped execution is the DEFAULT forward/backward for canonical (RAT)
-    structures, and its outputs are BITWISE identical to the per-layer
-    loop -- per segment, per depth, the same per-cell op in the same order;
-  * gradients through the grouped custom VJP match the per-layer VJP to
-    <= 1e-8 (measured 0.0 on the XLA path);
+    structures.  On the XLA path its outputs are BITWISE identical to the
+    per-layer loop -- per segment, per depth, the same per-cell op in the
+    same order -- and its gradients match the per-layer VJP to <= 1e-8
+    (measured 0.0);
+  * through the Pallas kernels (interpret mode on CPU) every cell runs the
+    per-layer kernel's own ops, but the per-layer op pads every K_out to a
+    128 lane while grouped interiors stay on the 16-lane K pad, and
+    XLA:CPU's dot associates its partial sums differently for the two
+    output widths.  So parity is float32-ulp-scaled, per tensor, against
+    ``1 + max|reference|``: forward <= PALLAS_FWD_ULPS (measured <= 0.63),
+    gradients <= PALLAS_GRAD_ULPS (measured <= 15.3: a one-ulp difference
+    of a log value near -90 becomes a relative difference of 90 ulps in
+    its exp, and the root weights' gradient sums such products);
   * gather/mixing (needs_buffer) structures compile to GATHER-grouped
     segments (core.plan.GatherTables) instead of falling back -- only the
     final (root) pair stays per-layer (tests/test_gather_grouped.py pins
@@ -58,6 +67,21 @@ def _pair_models(num_vars, depth, reps, k, nc, impl="xla", **kw):
     return m_g, m_p, params, x
 
 
+PALLAS_FWD_ULPS = 2
+PALLAS_GRAD_ULPS = 32
+F32_EPS = float(np.finfo(np.float32).eps)
+
+
+def _assert_ulps(a, b, ulps):
+    """Per tensor: max|a - b| <= ulps float32 ulps of 1 + max|b|."""
+    for la, lb in zip(jax.tree_util.tree_leaves(a),
+                      jax.tree_util.tree_leaves(b)):
+        if la.size:
+            diff = float(jnp.max(jnp.abs(la - lb)))
+            mag = float(jnp.max(jnp.abs(lb)))
+            assert diff <= ulps * F32_EPS * (1.0 + mag), (diff, mag)
+
+
 def _max_tree_diff(a, b):
     return max(
         float(jnp.max(jnp.abs(la - lb))) if la.size else 0.0
@@ -83,7 +107,7 @@ def test_grouped_forward_bitwise_pallas(shape):
     assert m_g.grouped_active
     out_g = m_g.forward(params, x)
     out_p = m_p.forward(params, x)
-    assert float(jnp.max(jnp.abs(out_g - out_p))) == 0.0
+    _assert_ulps(out_g, out_p, PALLAS_FWD_ULPS)
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -95,13 +119,17 @@ def test_grouped_grad_parity(impl):
 
     g_g = jax.grad(nll(m_g))(params)
     g_p = jax.grad(nll(m_p))(params)
-    assert _max_tree_diff(g_g, g_p) <= 1e-8
+    if impl == "xla":
+        assert _max_tree_diff(g_g, g_p) <= 1e-8
+    else:
+        _assert_ulps(g_g, g_p, PALLAS_GRAD_ULPS)
 
 
 def test_grouped_neg_inf_saturated_rows():
     """NEG_INF-saturated leaf rows (fully-marginalized scopes) flow through
-    the fused kernel's -inf padding contract: bitwise forward parity and
-    finite gradients on both paths."""
+    the fused kernel's -inf padding contract: bitwise forward parity (the
+    saturated rows leave this shape's contractions associated alike) and
+    finite gradients on both paths, equal to PALLAS_GRAD_ULPS."""
     m_g, m_p, params, x = _pair_models(64, 3, 3, 10, 1, impl="pallas")
     lr = m_g._leaf_rows(m_g.leaf_log_prob(params, x, None))
     lr = lr.at[:, ::3, :].set(NEG_INF)  # saturate every third leaf row
@@ -121,7 +149,7 @@ def test_grouped_neg_inf_saturated_rows():
     gr_g = jax.grad(loss(m_g))(lr)
     gr_p = jax.grad(loss(m_p))(lr)
     assert bool(jnp.all(jnp.isfinite(gr_g)))
-    assert _max_tree_diff(gr_g, gr_p) <= 1e-8
+    _assert_ulps(gr_g, gr_p, PALLAS_GRAD_ULPS)
 
 
 def test_needs_buffer_structures_gather_group_and_match():

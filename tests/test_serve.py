@@ -15,8 +15,10 @@ from repro.serve import (
     ServeEngine,
     SlotManager,
     direct_call,
+    format_report,
     mixed_requests,
     request_key,
+    run_benchmark,
 )
 
 
@@ -117,6 +119,18 @@ def test_mixed_stream_parity_with_direct_calls(small_net):
                 results[r.req_id].value[r.evidence_mask],
                 r.x[r.evidence_mask],
             )
+
+
+def test_run_benchmark_reports_parity(small_net):
+    """The shared measurement reports engine-vs-direct parity both absolute
+    and relative to 1 + |direct|; the relative form never exceeds the
+    absolute one and is what the serve CLI gates on."""
+    net, params = small_net
+    report = run_benchmark(net, params, mixed_requests(net.num_vars, 8),
+                           reps=1)
+    assert report["parity_max_abs_diff"] <= 1e-5
+    assert report["parity_max_rel_diff"] <= report["parity_max_abs_diff"]
+    assert "relative to 1 + |direct|" in format_report(report)
 
 
 def test_bucket_padding_never_leaks(small_net):
