@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs
 from repro.configs import EinetConfig, get_config
 from repro.core.em import (
     EMConfig,
@@ -197,42 +196,6 @@ def leaf_scatter_timing(arch: str = "einet_pd", batch: int = 32,
     }
 
 
-def segment_breakdown(model, params, x) -> dict:
-    """Per-segment time breakdown of one forward pass, measured eagerly.
-
-    The ``plan.segment`` spans in ``EiNet._forward_planned*`` normally fire
-    at trace time (the walk runs under jit); to charge real device time to
-    each segment, this enables obs tracing, installs
-    ``jax.block_until_ready`` as the obs sync hook (each span then blocks
-    on its own segment's output before closing) and runs one forward under
-    ``jax.disable_jit()``.  Returns {segment kind: {launches, eager_ms}} --
-    eager op dispatch inflates the absolute numbers vs the compiled step,
-    but the RELATIVE per-kind split is what the breakdown is for.
-    """
-    if not model.grouped_active:
-        return {}
-    mark = obs.num_events()
-    was_enabled = obs.enabled()
-    obs.configure(trace=True)
-    obs.set_sync(jax.block_until_ready)
-    try:
-        with jax.disable_jit():
-            jax.block_until_ready(model.log_likelihood(params, x))
-    finally:
-        obs.set_sync(None)
-        obs.configure(trace=was_enabled)
-    out: dict = {}
-    for e in obs.trace_events()[mark:]:
-        if e["name"] != "plan.segment":
-            continue
-        d = out.setdefault(e["args"]["kind"], {"launches": 0, "eager_ms": 0.0})
-        d["launches"] += 1
-        d["eager_ms"] += e["dur"] / 1e3
-    for d in out.values():
-        d["eager_ms"] = round(d["eager_ms"], 3)
-    return out
-
-
 def _per_step_path(model, em_cfg: EMConfig, num_microbatches: int):
     """The seed's training path: one jitted dispatch PER microbatch, host
     Python-loop accumulation, separately-jitted M-step + blend."""
@@ -299,7 +262,6 @@ def bench_cell(arch: str, cfg: EinetConfig, batch: int, microbatches: int,
     fused_s = _time_steps(fused, params, x, steps, reps)
     per_step_s = _time_steps(per_step, params, x, steps, reps)
     parity = _grad_parity(model)
-    segments = segment_breakdown(model, params, x)
     waiver = SPEEDUP_WAIVERS.get(arch)
     speedup = per_step_s / fused_s
     return {
@@ -320,8 +282,6 @@ def bench_cell(arch: str, cfg: EinetConfig, batch: int, microbatches: int,
         "speedup_waiver": waiver,
         # kernel launches per forward: per-layer loop vs depth-grouped plan
         "grouping": model.grouping_summary(),
-        # eager per-segment forward split (obs plan.segment spans)
-        "segment_breakdown": segments,
         "compile_fused_s": round(compile_fused_s, 2),
         "compile_per_step_s": round(compile_per_step_s, 2),
         "update_parity_max_abs_diff": step_parity,
@@ -356,12 +316,6 @@ def main(smoke: bool = False, archs=None, batch: int = 0, steps: int = 0,
             f"{g['launches_per_layer']}->{g['launches_grouped']}; "
             f"grad parity {r['grad_parity_max_abs_diff']:.2e}"
         )
-        if r["segment_breakdown"]:
-            split = ", ".join(
-                f"{k}: {v['launches']} launch(es) {v['eager_ms']:.1f} ms"
-                for k, v in sorted(r["segment_breakdown"].items())
-            )
-            print(f"  segments (eager forward): {split}")
         results.append(r)
     parity_ok = all(r["grad_parity_ok"] for r in results)
     # speedup gate: every row >= 1.0 or an explicit waiver (ISSUE: no silent

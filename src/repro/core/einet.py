@@ -61,6 +61,14 @@ QUERY_KINDS = (
 )
 
 
+def _lower_segment(seg) -> None:
+    """Record one plan segment's lowering: the walk runs while jit traces,
+    so this counts lowerings, not executions.  Device time per segment is
+    read from the ``plan.<kind>`` named scope in a profiler trace."""
+    obs.METRICS.counter("plan.segment.traces", kind=seg.kind).inc()
+    obs.event("plan.segment", kind=seg.kind, start=seg.start, stop=seg.stop)
+
+
 @dataclasses.dataclass
 class PairSpec:
     """Static gather tables for one (product-layer, sum-layer) pair."""
@@ -475,13 +483,8 @@ class EiNet:
         root_out = None
         for seg in self.exec_plan:
             last = self.pair_specs[seg.stop - 1]
-            # spans fire at TRACE time (this loop runs under jit/AOT
-            # lowering): the counter tallies segment lowerings, and an
-            # eager profiler (obs.set_sync + jax.disable_jit) reads real
-            # per-segment device time through obs.sync
-            obs.METRICS.counter("plan.segment.traces", kind=seg.kind).inc()
-            with obs.span("plan.segment", kind=seg.kind,
-                          start=seg.start, stop=seg.stop):
+            _lower_segment(seg)
+            with jax.named_scope(f"plan.{seg.kind}"):
                 if seg.fused:
                     ws = [einsum_w[t] for t in range(seg.start, seg.stop)]
                     s = grouped_log_einsum_exp(
@@ -504,12 +507,13 @@ class EiNet:
                     mix_out = log_mix_exp(
                         mixing_v[seg.stop - 1], ln, jnp.asarray(last.mix_mask)
                     )
-                obs.sync(s if mix_out is None else mix_out)
-            if last.is_final:
-                root_out = mix_out if last.mix_global is not None else s[:, 0, :]
-            else:
-                prev_out = s if mix_out is None else jnp.concatenate(
-                    [s, mix_out], axis=1)
+                if last.is_final:
+                    root_out = (
+                        mix_out if last.mix_global is not None else s[:, 0, :]
+                    )
+                else:
+                    prev_out = s if mix_out is None else jnp.concatenate(
+                        [s, mix_out], axis=1)
         if root_out.ndim == 3:
             root_out = root_out[:, 0, :]
         return root_out
@@ -533,10 +537,9 @@ class EiNet:
         buffer = leaf_out
         root_out = None
         for seg in self.exec_plan:
-            obs.METRICS.counter("plan.segment.traces", kind=seg.kind).inc()
+            _lower_segment(seg)
             if seg.kind == "gather":
-                with obs.span("plan.segment", kind=seg.kind,
-                              start=seg.start, stop=seg.stop):
+                with jax.named_scope("plan.gather"):
                     ws = tuple(
                         einsum_w[t] for t in range(seg.start, seg.stop)
                     )
@@ -552,10 +555,8 @@ class EiNet:
                     )
                     buffer = _cst(buffer, ("batch", "einet_nodes", None))
                     health_lib.tap_segment(buffer[:, w0:, :])
-                    obs.sync(buffer)
                 continue
-            with obs.span("plan.segment", kind=seg.kind,
-                          start=seg.start, stop=seg.stop):
+            with jax.named_scope(f"plan.{seg.kind}"):
                 spec = self.pair_specs[seg.start]
                 n_l = buffer[:, spec.left, :]
                 n_r = buffer[:, spec.right, :]
@@ -570,15 +571,14 @@ class EiNet:
                     mix_out = log_mix_exp(
                         mixing_v[seg.start], ln, jnp.asarray(spec.mix_mask)
                     )
-                obs.sync(s if mix_out is None else mix_out)
-            if spec.is_final:
-                root_out = (
-                    mix_out if spec.mix_global is not None else s[:, 0, :]
-                )
-            else:
-                new = s if mix_out is None else jnp.concatenate(
-                    [s, mix_out], axis=1)
-                buffer = jnp.concatenate([buffer, new], axis=1)
+                if spec.is_final:
+                    root_out = (
+                        mix_out if spec.mix_global is not None else s[:, 0, :]
+                    )
+                else:
+                    new = s if mix_out is None else jnp.concatenate(
+                        [s, mix_out], axis=1)
+                    buffer = jnp.concatenate([buffer, new], axis=1)
         if root_out.ndim == 3:
             root_out = root_out[:, 0, :]
         return root_out

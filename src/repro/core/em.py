@@ -51,6 +51,16 @@ def _psum(x, axis_names):
     return jax.lax.psum(x, axis_names) if axis_names else x
 
 
+def psum_statistics(stats, axis_names):
+    """Sum a statistics pytree over the data axes ``axis_names`` (the
+    distributed E-step's one collective), under the ``em.allreduce`` named
+    scope.  No axes: ``stats`` unchanged."""
+    if not axis_names:
+        return stats
+    with jax.named_scope("em.allreduce"):
+        return jax.tree_util.tree_map(lambda a: _psum(a, axis_names), stats)
+
+
 def leaf_scatter(model: EiNet, s_phi_pairs: jax.Array,
                  s_den_pairs: jax.Array):
     """Fan per-pair leaf statistics out to parameter layout: (P, K, |T|) ->
@@ -93,9 +103,24 @@ def em_statistics(
       s_den:    (D, K, R)                   -- sum_x p_L(x)
       n_class:  (num_classes,)
       ll:       scalar mean log-likelihood (for monitoring)
+
+    Named scopes (HLO ``op_name``, read from a profiler trace): the whole
+    E-step under ``em.estep``; inside it ``einet.leaf`` (leaf EF densities
+    and their segment-sum into leaf rows), the plan walk's ``plan.<kind>``
+    (forward, and its backward under ``transpose(jvp(...))``),
+    ``em.leaf_stats`` (leaf sufficient statistics and their scatter) and
+    ``em.allreduce`` (the psum over ``axis_names``).
     """
-    e = model.leaf_log_prob(params, x, None)
-    leaf_rows = model._leaf_rows(e)  # (B, num_leaves, K)
+    with jax.named_scope("em.estep"):
+        stats = _em_statistics(model, params, x)
+    return psum_statistics(stats, axis_names)
+
+
+def _em_statistics(model: EiNet, params: Dict[str, Any],
+                   x: jax.Array) -> Dict[str, Any]:
+    with jax.named_scope("einet.leaf"):
+        e = model.leaf_log_prob(params, x, None)
+        leaf_rows = model._leaf_rows(e)  # (B, num_leaves, K)
     prior = params["class_prior"]
 
     def batch_ll(einsum_w, mixing_v, lr, logprior):
@@ -124,19 +149,21 @@ def em_statistics(
     # leaf posteriors out to (d, k, r): every (variable, replica) pair belongs
     # to exactly one leaf, so the fan-out is a unique-index scatter.
     ls = model.leaf_spec
-    t = model.ef.sufficient_statistics(x)  # (B, D, |T|)
     cst = sharding_lib.constraint
-    g_pairs = cst(g_leaf[:, ls.pair_leaf, :], ("batch", "einet_nodes", None))
-    t_pairs = cst(t[:, ls.pair_var, :], ("batch", "einet_nodes", None))
-    s_phi_pairs = cst(jnp.einsum("bpk,bpt->pkt", g_pairs, t_pairs,
-                                 precision=layers.PRECISION),
-                      ("einet_nodes", None, None))
-    s_den_pairs = cst(jnp.sum(g_pairs, axis=0), ("einet_nodes", None))
-    s_phi, s_den = leaf_scatter(model, s_phi_pairs, s_den_pairs)
+    with jax.named_scope("em.leaf_stats"):
+        t = model.ef.sufficient_statistics(x)  # (B, D, |T|)
+        g_pairs = cst(g_leaf[:, ls.pair_leaf, :],
+                      ("batch", "einet_nodes", None))
+        t_pairs = cst(t[:, ls.pair_var, :], ("batch", "einet_nodes", None))
+        s_phi_pairs = cst(jnp.einsum("bpk,bpt->pkt", g_pairs, t_pairs,
+                                     precision=layers.PRECISION),
+                          ("einet_nodes", None, None))
+        s_den_pairs = cst(jnp.sum(g_pairs, axis=0), ("einet_nodes", None))
+        s_phi, s_den = leaf_scatter(model, s_phi_pairs, s_den_pairs)
     # dlogP/dlog(prior_c) = sum_x posterior(c | x): the expected class counts
     n_class = g_prior
 
-    stats = {
+    return {
         "n_einsum": n_einsum,
         "n_mixing": n_mixing,
         "s_phi": s_phi,
@@ -145,9 +172,6 @@ def em_statistics(
         "ll": val,
         "count": jnp.asarray(x.shape[0], jnp.float32),
     }
-    if axis_names:
-        stats = jax.tree_util.tree_map(lambda a: _psum(a, axis_names), stats)
-    return stats
 
 
 def m_step(
@@ -155,7 +179,13 @@ def m_step(
     stats: Dict[str, Any],
     cfg: EMConfig,
 ) -> Dict[str, Any]:
-    """Exact M-step from accumulated statistics."""
+    """Exact M-step from accumulated statistics (named scope ``em.mstep``)."""
+    with jax.named_scope("em.mstep"):
+        return _m_step(model, stats, cfg)
+
+
+def _m_step(model: EiNet, stats: Dict[str, Any],
+            cfg: EMConfig) -> Dict[str, Any]:
     alpha = cfg.laplace_alpha
     einsum_w = [
         normalize_einsum_weights(n + alpha, floor=cfg.stat_floor)
@@ -210,19 +240,23 @@ def blend_params(
     Shared by ``stochastic_em_update`` and the compiled training pipeline
     (``repro.train``), so both paths apply the identical update -- including
     the phi re-projection that keeps EF parameters in their valid domain
-    after interpolation.
+    after interpolation.  Under the ``em.mstep`` named scope, with the
+    M-step.
     """
     lam = step_size
 
     def blend(old, new):
         return (1.0 - lam) * old + lam * new
 
-    return {
-        "phi": model.ef.project_phi(blend(params["phi"], mini["phi"])),
-        "einsum": [blend(o, n) for o, n in zip(params["einsum"], mini["einsum"])],
-        "mixing": [blend(o, n) for o, n in zip(params["mixing"], mini["mixing"])],
-        "class_prior": blend(params["class_prior"], mini["class_prior"]),
-    }
+    with jax.named_scope("em.mstep"):
+        return {
+            "phi": model.ef.project_phi(blend(params["phi"], mini["phi"])),
+            "einsum": [blend(o, n)
+                       for o, n in zip(params["einsum"], mini["einsum"])],
+            "mixing": [blend(o, n)
+                       for o, n in zip(params["mixing"], mini["mixing"])],
+            "class_prior": blend(params["class_prior"], mini["class_prior"]),
+        }
 
 
 def stochastic_em_update(

@@ -31,7 +31,13 @@ from repro.dist import sharding as shlib
 from repro.launch import cells as dr
 from repro.launch.mesh import dp_shards, make_mesh_for
 from repro.obs import health as health_lib
-from repro.train import TrainConfig, make_em_step, make_sharded_em_step
+from repro.train import (
+    TrainConfig,
+    make_em_step,
+    make_sharded_em_step,
+    record_step,
+    run_step,
+)
 
 # --smoke: the CI trace-smoke profile -- a RAT shape small enough to train
 # in seconds on CPU but deep enough to depth-group, with health telemetry
@@ -216,15 +222,11 @@ def main():
             step_jit = mx.make_mixture_em_step(model, mcfg)
 
             def step_fn(state, batch):
-                x = jnp.asarray(batch["x"])
-                with obs.timed("train.step", metric="train.step.seconds"):
-                    p, ll = step_jit(state["params"], x)
-                    state["last_ll"] = float(ll)
-                obs.METRICS.counter("train.examples.count").inc(
-                    int(x.shape[0]))
-                obs.METRICS.gauge("train.ll.last").set(state["last_ll"])
+                (p, _), ll = run_step(step_jit, state["params"], batch["x"])
+                with obs.span("train.record"):
+                    record_step(len(batch["x"]), ll)
                 return {"params": p, "step": state["step"] + 1,
-                        "last_ll": state["last_ll"]}
+                        "last_ll": ll}
 
             init_state = {"params": params, "step": jnp.zeros((), jnp.int32),
                           "last_ll": 0.0}
@@ -290,22 +292,15 @@ def main():
                 to_device = jnp.asarray
 
             def step_fn(state, batch):
-                x = to_device(batch["x"])
-                with obs.timed("train.step", metric="train.step.seconds"):
-                    if health_on:
-                        p, ll, hv = step_jit(state["params"], x)
-                    else:
-                        p, ll = step_jit(state["params"], x)
-                        hv = None
-                    state["last_ll"] = float(ll)
-                obs.METRICS.counter("train.examples.count").inc(
-                    int(np.asarray(batch["x"]).shape[0]))
-                obs.METRICS.gauge("train.ll.last").set(state["last_ll"])
-                if watcher is not None:
-                    health_lib.publish(model.health_spec, hv)
-                    watcher.observe(int(state["step"]), hv, p)
-                return {"params": p, "step": state["step"] + 1,
-                        "last_ll": state["last_ll"]}
+                out, ll = run_step(step_jit, state["params"], batch["x"],
+                                   to_device)
+                with obs.span("train.record"):
+                    record_step(len(batch["x"]), ll)
+                    if watcher is not None:
+                        health_lib.publish(model.health_spec, out[2])
+                        watcher.observe(int(state["step"]), out[2], out[0])
+                return {"params": out[0], "step": state["step"] + 1,
+                        "last_ll": ll}
 
             init_state = {"params": params, "step": jnp.zeros((), jnp.int32),
                           "last_ll": 0.0}

@@ -3,7 +3,8 @@
 Three parts (see the submodule docstrings):
 
   * :mod:`repro.obs.trace`   -- nestable ``span(...)`` context managers,
-    Chrome/Perfetto ``trace_event`` export, the ``REPRO_TRACE`` switch;
+    Chrome/Perfetto ``trace_event`` export, and the same spans as
+    ``jax.profiler`` annotations; the ``REPRO_TRACE`` switch;
   * :mod:`repro.obs.metrics` -- counters / gauges / log-bucket histograms
     with ``percentile(q)``, snapshot-able to plain JSON;
   * :mod:`repro.obs.events`  -- the shared compile-event hook fed by
@@ -12,8 +13,8 @@ Three parts (see the submodule docstrings):
 
 Instrumented subsystems tag spans/metrics as ``subsystem.verb.unit``:
 ``serve.request.seconds{kind,bucket}``, ``compile.cache.misses{kind}``,
-``plan.segment`` (trace-time, per execution-plan segment), ``train.step.
-seconds``, ``eval.inpaint.seconds{mask}``.  The launch CLIs accept
+``plan.segment`` (an instant event per execution-plan segment lowered),
+``train.step.seconds``, ``eval.inpaint.seconds{mask}``.  The launch CLIs accept
 ``--trace out.json`` and print one ``[obs]`` summary line at exit
 (:func:`format_summary`).
 
@@ -46,9 +47,7 @@ from repro.obs.trace import (
     now,
     num_events,
     reset,
-    set_sync,
     span,
-    sync,
     timed,
     trace_events,
 )
@@ -58,8 +57,8 @@ __all__ = [
     "Span", "Timed", "cache_event", "compile_event", "configure",
     "dropped_events", "enabled", "event", "export_trace", "format_summary",
     "now", "num_events", "on_compile", "percentile_from_counts",
-    "remove_compile_listener", "reset", "set_sync", "span", "summary",
-    "sync", "timed", "trace_events",
+    "remove_compile_listener", "reset", "span", "summary",
+    "timed", "trace_events",
 ]
 
 

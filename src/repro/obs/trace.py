@@ -1,12 +1,21 @@
-"""Tracing spans: nestable context managers -> Chrome/Perfetto trace JSON.
+"""Tracing spans: nestable context managers -> Chrome/Perfetto trace JSON
+and the ``jax.profiler`` trace.
 
 The tracing half of ``repro.obs``: ``span("serve.execute", kind=..., ...)``
-wraps a region of host code and, when tracing is enabled, appends one Chrome
-``trace_event`` *complete* event (``ph: "X"`` with ``ts``/``dur`` in
-microseconds) to a thread-safe in-process buffer that ``export_trace(path)``
-writes as a JSON file loadable by ``chrome://tracing`` / ui.perfetto.dev.
-Nesting needs no bookkeeping -- the viewer reconstructs the stack from
-``ts``/``dur`` containment per thread.
+wraps a region of host code and, when tracing is enabled, writes it to two
+sinks:
+
+  * one Chrome ``trace_event`` *complete* event (``ph: "X"`` with
+    ``ts``/``dur`` in microseconds) in a thread-safe in-process buffer that
+    ``export_trace(path)`` writes as a JSON file loadable by
+    ``chrome://tracing`` / ui.perfetto.dev.  Nesting needs no bookkeeping --
+    the viewer reconstructs the stack from ``ts``/``dur`` containment per
+    thread;
+  * one ``jax.profiler.TraceAnnotation`` of the same name, the span's args
+    as its metadata.  While a ``jax.profiler`` trace runs, the span lands
+    on the profiler's host plane, on the clock of the program launches, so
+    a device trace can be read against the program's own spans.  Outside a
+    profiler trace the annotation records nothing.
 
 Enable switches (the disabled path must cost ~nothing -- ``span()`` returns
 a shared no-op singleton, one attribute read + one ``if``):
@@ -28,6 +37,7 @@ Two flavours of timed region:
 
 jax-free and numpy-free by design: ``repro.obs`` must be importable from
 every layer (including ``repro.compile`` before jax loads) without cycles.
+jax is imported at the first span opened with tracing on.
 """
 
 from __future__ import annotations
@@ -37,7 +47,7 @@ import json
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 # trace-time clock origin: event ts are microseconds since process start
 _T0_NS = time.perf_counter_ns()
@@ -59,13 +69,13 @@ def _env_path(value: str) -> Optional[str]:
 
 
 class _TraceState:
-    __slots__ = ("enabled", "sync_fn", "lock", "events", "dropped",
+    __slots__ = ("enabled", "annotation", "lock", "events", "dropped",
                  "export_path", "_atexit_armed")
 
     def __init__(self):
         env = os.environ.get("REPRO_TRACE", "")
         self.enabled = _env_truthy(env)
-        self.sync_fn: Optional[Callable[[Any], Any]] = None
+        self.annotation = None  # jax.profiler.TraceAnnotation, imported lazily
         self.lock = threading.Lock()
         self.events: List[Dict[str, Any]] = []
         self.dropped = 0
@@ -111,23 +121,16 @@ def now() -> float:
     return time.perf_counter()
 
 
-def set_sync(fn: Optional[Callable[[Any], Any]]) -> None:
-    """Install a synchronization callback for :func:`sync` (e.g.
-    ``jax.block_until_ready`` while timing an eager plan walk).  ``None``
-    (the default) makes :func:`sync` a no-op, so instrumented library code
-    pays nothing in production."""
-    _STATE.sync_fn = fn
+def _annotation(name: str, args: Dict[str, Any]):
+    """An entered ``jax.profiler.TraceAnnotation`` for one span."""
+    cls = _STATE.annotation
+    if cls is None:
+        from jax.profiler import TraceAnnotation
 
-
-def sync(value: Any) -> Any:
-    """Synchronize ``value`` through the installed callback (no-op by
-    default).  Instrumented compute sites call this just before their span
-    closes so an eager-mode profiler can charge device time to the right
-    span."""
-    fn = _STATE.sync_fn
-    if fn is not None:
-        fn(value)
-    return value
+        cls = _STATE.annotation = TraceAnnotation
+    ann = cls(name, **args)
+    ann.__enter__()
+    return ann
 
 
 def _append(event: Dict[str, Any]) -> None:
@@ -141,19 +144,22 @@ def _append(event: Dict[str, Any]) -> None:
 class Span:
     """One traced region; use via ``with span("name", key=val): ...``."""
 
-    __slots__ = ("name", "args", "_t0")
+    __slots__ = ("name", "args", "_t0", "_ann")
 
     def __init__(self, name: str, args: Dict[str, Any]):
         self.name = name
         self.args = args
         self._t0 = 0
+        self._ann = None
 
     def __enter__(self) -> "Span":
+        self._ann = _annotation(self.name, self.args)
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(None, None, None)
         _append({
             "ph": "X",
             "name": self.name,
@@ -197,7 +203,7 @@ class Timed:
     trace (when enabled) and the always-on metrics registry.
     """
 
-    __slots__ = ("name", "args", "metric", "seconds", "_t0")
+    __slots__ = ("name", "args", "metric", "seconds", "_t0", "_ann")
 
     def __init__(self, name: str, metric: Optional[str] = None,
                  **args: Any):
@@ -206,13 +212,19 @@ class Timed:
         self.metric = metric
         self.seconds = 0.0
         self._t0 = 0
+        self._ann = None
 
     def __enter__(self) -> "Timed":
+        if _STATE.enabled:
+            self._ann = _annotation(self.name, self.args)
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         self.seconds = (t1 - self._t0) / 1e9
         if self.metric is not None:
             from repro.obs.metrics import METRICS

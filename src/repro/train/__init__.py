@@ -7,6 +7,8 @@ from repro.train.pipeline import (
     make_em_step,
     make_sharded_em_step,
     microbatched_em_statistics,
+    record_step,
+    run_step,
     stochastic_em_update_microbatched,
 )
 
@@ -17,5 +19,7 @@ __all__ = [
     "make_em_step",
     "make_sharded_em_step",
     "microbatched_em_statistics",
+    "record_step",
+    "run_step",
     "stochastic_em_update_microbatched",
 ]

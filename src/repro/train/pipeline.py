@@ -41,6 +41,7 @@ from repro.core.em import (
     blend_params,
     em_statistics,
     m_step,
+    psum_statistics,
     zeros_like_statistics,
 )
 from repro.obs import health as health_lib
@@ -149,11 +150,7 @@ def microbatched_em_statistics(
         acc = zeros_like_statistics(model, params)
         for i in range(num_microbatches):
             acc, _ = body(acc, xm[i])
-    if axis_names:
-        acc = jax.tree_util.tree_map(
-            lambda a: jax.lax.psum(a, axis_names), acc
-        )
-    return acc
+    return psum_statistics(acc, axis_names)
 
 
 def _probe_slice(x: jax.Array, num_microbatches: int) -> jax.Array:
@@ -372,6 +369,10 @@ def fit(
     (``health_policy`` configures it): a divergence dumps an incident bundle
     and -- under the default "abort" policy -- raises
     :class:`repro.obs.health.DivergenceError`.
+
+    Each step runs in the spans of :func:`run_step`, then ``train.record``
+    around the bookkeeping (metrics, health, ``on_step``); the four
+    ``train.*`` spans carry ``step=i``.
     """
     step_fn = make_em_step(model, cfg)
     health_on = _resolve_step_health(model, cfg)
@@ -383,21 +384,42 @@ def fit(
         if num_steps is not None and i >= num_steps:
             break
         x = batch["x"] if isinstance(batch, dict) else batch
-        x = jnp.asarray(x)
-        # float(ll) blocks on the device, so the timed region covers the
-        # full step (dispatch + compute), not just dispatch
-        with obs.timed("train.step", metric="train.step.seconds"):
-            if health_on:
-                params, ll, hv = step_fn(params, x)
-            else:
-                params, ll = step_fn(params, x)
-                hv = None
-            lls.append(float(ll))
-        obs.METRICS.counter("train.examples.count").inc(int(x.shape[0]))
-        obs.METRICS.gauge("train.ll.last").set(lls[-1])
-        if watcher is not None:
-            health_lib.publish(model.health_spec, hv)
-            watcher.observe(i, hv, params)
-        if on_step is not None:
-            on_step(i, lls[-1])
+        out, ll = run_step(step_fn, params, x, step=i)
+        params = out[0]
+        lls.append(ll)
+        with obs.span("train.record", step=i):
+            record_step(len(x), ll)
+            if watcher is not None:
+                health_lib.publish(model.health_spec, out[2])
+                watcher.observe(i, out[2], params)
+            if on_step is not None:
+                on_step(i, ll)
     return params, lls
+
+
+def run_step(step_fn: Callable, params: Any, x: Any,
+             to_device: Callable = jnp.asarray, **args: Any):
+    """One step of a training loop: copy the host batch ``x`` to the device,
+    run ``step_fn(params, x)``, and wait for its mean LL (the step's second
+    output).  Returns (the step's outputs, the mean LL as a float).
+
+    The host boundaries are ``repro.obs`` spans, named alike in every loop
+    (``fit``, ``launch.train``) and carrying ``args``: ``train.copy``
+    around the copy, then inside ``timed("train.step")`` (the step's
+    ``train.step.seconds`` metric, dispatch plus device time)
+    ``train.dispatch`` around the call and ``train.sync`` around the wait.
+    """
+    with obs.span("train.copy", **args):
+        x = to_device(x)
+    with obs.timed("train.step", metric="train.step.seconds"):
+        with obs.span("train.dispatch", **args):
+            out = step_fn(params, x)
+        with obs.span("train.sync", **args):
+            ll = float(out[1])
+    return out, ll
+
+
+def record_step(examples: int, ll: float) -> None:
+    """A step's always-on metrics: examples seen and the last mean LL."""
+    obs.METRICS.counter("train.examples.count").inc(examples)
+    obs.METRICS.gauge("train.ll.last").set(ll)

@@ -1,10 +1,14 @@
 """Observability tests: span nesting + Chrome-trace export schema, log-bucket
 histogram percentiles against numpy, steady-state baseline subtraction,
 counter thread-safety, the disabled path costing nothing AND changing
-nothing (bitwise-identical serve results traced vs untraced), and the
-single-source compile-event accounting shared with ``analysis.sentry``."""
+nothing (bitwise-identical serve results traced vs untraced), spans landing
+in the ``jax.profiler`` trace, and the single-source compile-event
+accounting shared with ``analysis.sentry``."""
 
 import json
+import pathlib
+import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -27,7 +31,6 @@ def obs_clean_slate():
     yield
     obs.configure(trace=False)
     obs.reset()
-    obs.set_sync(None)
 
 
 # ------------------------------------------------------------------- tracing
@@ -81,6 +84,69 @@ def test_disabled_span_is_shared_noop_and_buffers_nothing():
         pass
     obs.event("nope")
     assert obs.num_events() == 0
+
+
+def _profiled_host_events(tmp_path, body):
+    """Run ``body`` under a ``jax.profiler`` trace; the host plane's events
+    as {name: [stats dict, ...]}."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    [path] = list(tmp_path.rglob("*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(str(path))
+    out = {}
+    for plane in data.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                out.setdefault(e.name, []).append(
+                    (e.start_ns, e.duration_ns, dict(e.stats)))
+    return out
+
+
+def _spans_and_timed():
+    with obs.span("unit.outer", step=3, kind="a"):
+        with obs.timed("unit.timed", idx=1):
+            sum(range(1000))
+
+
+@pytest.mark.parametrize("on", [True, False], ids=["enabled", "disabled"])
+def test_spans_land_in_profiler_trace(tmp_path, on):
+    """With tracing on, span and timed open a profiler annotation of the
+    same name carrying their args, nested as in the code; with it off,
+    nothing lands (and span stays the null singleton)."""
+    obs.configure(trace=on)
+    events = _profiled_host_events(tmp_path, _spans_and_timed)
+    if not on:
+        assert "unit.outer" not in events and "unit.timed" not in events
+        assert obs.span("unit.outer") is obs.span("unit.other")
+        return
+    [(o_start, o_dur, o_stats)] = events["unit.outer"]
+    [(t_start, t_dur, t_stats)] = events["unit.timed"]
+    assert o_stats == {"step": 3, "kind": "a"}
+    assert t_stats == {"idx": 1}
+    assert o_start <= t_start and t_start + t_dur <= o_start + o_dur
+    # the Chrome buffer is the same switch's other sink
+    assert [e["name"] for e in obs.trace_events()] == ["unit.timed",
+                                                       "unit.outer"]
+
+
+def test_obs_imports_without_jax():
+    """``repro.obs`` stays importable before (and without) jax: jax is
+    imported only at the first span opened with tracing on."""
+    code = ("import sys; import repro.obs as o; "
+            "assert 'jax' not in sys.modules; "
+            "o.span('a').__enter__(); assert 'jax' not in sys.modules; "
+            "o.configure(trace=True); "
+            "o.span('a').__enter__(); assert 'jax' in sys.modules")
+    src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env={"PYTHONPATH": src,
+                                       "JAX_PLATFORMS": "cpu"})
+    assert r.returncode == 0, r.stderr
 
 
 def test_timed_measures_even_when_disabled_and_feeds_metric():

@@ -159,3 +159,46 @@ def test_einet_loader_explicit_shard_override():
     own = ld.batch_at(0)["x"][:, 0]
     other = ld.batch_at(0, shard=1)["x"][:, 0]
     assert not set(own.astype(int)) & set(other.astype(int))
+
+
+def test_fit_traced_is_bitwise_untraced_and_spans_each_step(setup):
+    """Tracing changes nothing fit computes, and records the four host
+    boundaries of every step once: ``train.copy`` before ``train.step``,
+    ``train.dispatch`` then ``train.sync`` inside it, ``train.record``
+    after it, each with ``step=i``."""
+    from repro import obs
+
+    net, params, _ = setup
+    data = jax.random.normal(jax.random.PRNGKey(7), (192, 10))
+    batches = [np.asarray(data[i * 64: (i + 1) * 64]) for i in range(3)]
+    cfg = TrainConfig(donate=False)
+    p0, ll0 = fit(net, params, batches, cfg)
+    obs.configure(trace=True)
+    obs.reset()
+    try:
+        p1, ll1 = fit(net, params, batches, cfg)
+        events = [e for e in obs.trace_events() if e["ph"] == "X"]
+    finally:
+        obs.configure(trace=False)
+        obs.reset()
+    assert ll0 == ll1
+    for a, b in zip(jax.tree_util.tree_leaves(p0),
+                    jax.tree_util.tree_leaves(p1)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    steps = [e for e in events if e["name"] == "train.step"]
+    assert len(steps) == len(batches)
+    for i, step in enumerate(steps):
+        t0, t1 = step["ts"], step["ts"] + step["dur"]
+        got = {}
+        for name in ("train.copy", "train.dispatch", "train.sync",
+                     "train.record"):
+            [got[name]] = [e for e in events if e["name"] == name
+                           and e["args"] == {"step": i}]
+        for name in ("train.dispatch", "train.sync"):
+            e = got[name]
+            assert t0 <= e["ts"] and e["ts"] + e["dur"] <= t1 + 1e-3
+        assert got["train.dispatch"]["ts"] <= got["train.sync"]["ts"]
+        copy, record = got["train.copy"], got["train.record"]
+        assert copy["ts"] + copy["dur"] <= t0 + 1e-3
+        assert t1 <= record["ts"] + 1e-3
