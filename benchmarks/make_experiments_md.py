@@ -133,8 +133,9 @@ def bench_summary() -> str:
         if sc:
             parts.append(
                 f"**Leaf EM fan-out** (`BENCH_train.json`, {sc.get('arch')} "
-                f"at batch {sc.get('batch')}): the leaf-statistic scatter "
-                f"(unique-index `.at[flat].set` into (D, K, R, |T|)) costs "
+                f"at batch {sc.get('batch')}): the leaf statistics "
+                f"(`core.em.leaf_statistics`, fan-out to (D, K, R, |T|) "
+                f"included) cost "
                 f"{sc.get('leaf_scatter_ms')} ms of the "
                 f"{sc.get('em_statistics_ms')} ms `em_statistics` call "
                 f"({100 * sc.get('scatter_fraction', 0):.1f}%) — the ROADMAP "

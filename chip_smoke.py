@@ -199,10 +199,10 @@ def precision_phase(model, params, x, step_jit):
     d_rows = row_rel_diff(ll, ll_ref)
     _, step_ll = step_jit(params, x)
     d_step = row_rel_diff(step_ll, jnp.mean(ll_ref))
-    leaf = jax.jit(model.leaf_log_prob)(params, x, None)
-    leaf_ref = highest(model.leaf_log_prob)(params, x, None)
+    leaf = jax.jit(model.leaf_rows)(params, x)
+    leaf_ref = highest(model.leaf_rows)(params, x)
     say(f"precision: per-row LL rel diff {d_rows!r} (tol {LL_RTOL}), "
-        f"EM-step mean LL rel diff {d_step!r}, leaf log-prob max abs diff "
+        f"EM-step mean LL rel diff {d_step!r}, leaf rows max abs diff "
         f"{float(jnp.max(jnp.abs(leaf - leaf_ref)))!r}, "
         f"ll range [{float(jnp.min(ll_ref))!r}, {float(jnp.max(ll_ref))!r}]")
     check(bool(jnp.all(jnp.isfinite(ll))), "non-finite per-row LL")

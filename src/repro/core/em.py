@@ -61,31 +61,44 @@ def psum_statistics(stats, axis_names):
         return jax.tree_util.tree_map(lambda a: _psum(a, axis_names), stats)
 
 
-def leaf_scatter(model: EiNet, s_phi_pairs: jax.Array,
-                 s_den_pairs: jax.Array):
-    """Fan per-pair leaf statistics out to parameter layout: (P, K, |T|) ->
-    (D, K, R, |T|) and (P, K) -> (D, K, R).
+def leaf_statistics(model: EiNet, g_leaf: jax.Array, x: jax.Array):
+    """Leaf sufficient statistics from the leaf rows' cotangent ``g_leaf``
+    (B, num_leaves, K), each leaf density's posterior p_L(x):
+    s_phi (D, K, R, |T|) = sum_x p_L(x) T(x) and s_den (D, K, R) =
+    sum_x p_L(x).
 
-    Every (variable, replica) pair belongs to exactly one leaf, so this is a
-    unique-index scatter with zero cross-shard traffic under node sharding
-    (§Perf einet it.3).  THE one definition of the fan-out: the single-model
-    E-step, the vmapped mixture E-step (``repro.mixture.train``) and the
-    fuse-or-not microbenchmark (``benchmarks/bench_train.py``) all time and
-    run this exact op.
+    Computed in the run layout (``EiNet.leaf_rows``) with the batch minor:
+    one contraction over the batch per leaf, ``g_leaf`` against T(x) in run
+    order, and the batch sum of ``g_leaf`` broadcast over the run.  A
+    unique-index permutation then takes the run entries, tensors of
+    parameter size, to (D, K, R, ...): every (variable, replica) entry
+    belongs to at most one leaf (0 where none holds it), and no entry maps
+    to a pad, so pads drop out.  THE one definition: the single-model
+    E-step and the vmapped mixture E-step (``repro.mixture.train``) both
+    run it.
     """
+    cst = sharding_lib.constraint
+    t = jnp.moveaxis(
+        model.ef.sufficient_statistics(model.run_columns(x)), -1, -2)
+    # (B, leaves, K) x (run, leaves, |T|, B) -> (leaves, K, run, |T|)
+    s_phi = cst(jax.lax.dot_general(g_leaf, t, (((0,), (3,)), ((1,), (1,))),
+                                    precision=layers.PRECISION),
+                ("einet_nodes", None, None, None))
+    s_den = cst(jnp.sum(g_leaf, axis=0), ("einet_nodes", None))  # (leaves, K)
+    n, k, run, tdim = s_phi.shape
+    s_phi = jnp.transpose(s_phi, (2, 0, 1, 3)).reshape(run * n, k, tdim)
+    s_den = jnp.broadcast_to(s_den[None], (run, n, k)).reshape(run * n, k)
+    return _to_param_layout(model, s_phi), _to_param_layout(model, s_den)
+
+
+def _to_param_layout(model: EiNet, runs: jax.Array) -> jax.Array:
+    """(run * num_leaves, K, ...) in run order -> (D, K, R, ...), by the
+    static permutation ``leaf_spec.param_index``."""
     ls = model.leaf_spec
-    d, k, r = model.num_vars, model.K, ls.num_replica
-    tdim = model.ef.num_stats
-    flat = ls.pair_var * r + ls.pair_rep  # unique per pair entry
-    s_phi = (
-        jnp.zeros((d * r, k, tdim)).at[flat].set(s_phi_pairs)
-        .reshape(d, r, k, tdim).swapaxes(1, 2)
-    )  # (D, K, R, |T|)
-    s_den = (
-        jnp.zeros((d * r, k)).at[flat].set(s_den_pairs)
-        .reshape(d, r, k).swapaxes(1, 2)
-    )  # (D, K, R)
-    return s_phi, s_den
+    padded = jnp.concatenate([runs, jnp.zeros_like(runs[:1])])
+    out = padded[ls.param_index].reshape(
+        (model.num_vars, ls.num_replica) + runs.shape[1:])
+    return jnp.swapaxes(out, 1, 2)
 
 
 def em_statistics(
@@ -106,9 +119,9 @@ def em_statistics(
 
     Named scopes (HLO ``op_name``, read from a profiler trace): the whole
     E-step under ``em.estep``; inside it ``einet.leaf`` (leaf EF densities
-    and their segment-sum into leaf rows), the plan walk's ``plan.<kind>``
-    (forward, and its backward under ``transpose(jvp(...))``),
-    ``em.leaf_stats`` (leaf sufficient statistics and their scatter) and
+    summed into leaf rows), the plan walk's ``plan.<kind>`` (forward, and
+    its backward under ``transpose(jvp(...))``), ``em.leaf_stats`` (leaf
+    sufficient statistics in parameter layout) and
     ``em.allreduce`` (the psum over ``axis_names``).
     """
     with jax.named_scope("em.estep"):
@@ -119,12 +132,11 @@ def em_statistics(
 def _em_statistics(model: EiNet, params: Dict[str, Any],
                    x: jax.Array) -> Dict[str, Any]:
     with jax.named_scope("einet.leaf"):
-        e = model.leaf_log_prob(params, x, None)
-        leaf_rows = model._leaf_rows(e)  # (B, num_leaves, K)
+        leaf_rows = model.leaf_rows(params, x)  # (B, num_leaves, K)
     prior = params["class_prior"]
 
     def batch_ll(einsum_w, mixing_v, lr, logprior):
-        root = model.forward_from_e(einsum_w, mixing_v, None, leaf_rows=lr)
+        root = model.forward_from_leaves(einsum_w, mixing_v, lr)
         ll = jax.scipy.special.logsumexp(root + logprior[None, :], axis=-1)
         return jnp.sum(ll)
 
@@ -145,21 +157,10 @@ def _em_statistics(model: EiNet, params: Dict[str, Any],
     n_einsum = [w * g for w, g in zip(params["einsum"], g_einsum)]
     n_mixing = [v * g for v, g in zip(params["mixing"], g_mixing)]
     # leaf statistics.  We differentiate wrt the LEAF ROWS (node-sharded, no
-    # cross-shard scatter in the transpose -- §Perf einet it.3) and fan the
-    # leaf posteriors out to (d, k, r): every (variable, replica) pair belongs
-    # to exactly one leaf, so the fan-out is a unique-index scatter.
-    ls = model.leaf_spec
-    cst = sharding_lib.constraint
+    # cross-shard scatter in the transpose -- §Perf einet it.3): g_leaf is
+    # each leaf density's posterior, contracted with T(x) per leaf.
     with jax.named_scope("em.leaf_stats"):
-        t = model.ef.sufficient_statistics(x)  # (B, D, |T|)
-        g_pairs = cst(g_leaf[:, ls.pair_leaf, :],
-                      ("batch", "einet_nodes", None))
-        t_pairs = cst(t[:, ls.pair_var, :], ("batch", "einet_nodes", None))
-        s_phi_pairs = cst(jnp.einsum("bpk,bpt->pkt", g_pairs, t_pairs,
-                                     precision=layers.PRECISION),
-                          ("einet_nodes", None, None))
-        s_den_pairs = cst(jnp.sum(g_pairs, axis=0), ("einet_nodes", None))
-        s_phi, s_den = leaf_scatter(model, s_phi_pairs, s_den_pairs)
+        s_phi, s_den = leaf_statistics(model, g_leaf, x)
     # dlogP/dlog(prior_c) = sum_x posterior(c | x): the expected class counts
     n_class = g_prior
 

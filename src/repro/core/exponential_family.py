@@ -21,9 +21,10 @@ Each EF below provides:
                                           [1e-6, 1e-2] after each EM update)
 
 Parameter tensors have shape ``(D, K, R, |T|)``: D variables, K densities per
-leaf vector, R replica (paper notation).  ``log_prob`` evaluates all D*K*R
-densities in a handful of parallel primitives (inner product + A(theta)),
-exactly the layout of Eq. "E" in §3.4.
+leaf vector, R replica (paper notation).  ``log_density`` evaluates the
+densities per entry (inner product - A(theta)); the leaf layer
+(``EiNet.leaf_rows``) calls it on each leaf's (variable, replica) entries in
+turn, rather than on the paper's whole D x K x R tensor "E" of §3.4.
 """
 
 from __future__ import annotations
@@ -33,8 +34,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-
-from repro.core import layers
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,23 +80,22 @@ class ExponentialFamily:
         return jnp.zeros((), jnp.float32)
 
     # --- shared machinery ----------------------------------------------------
-    def log_prob(self, x: jax.Array, phi: jax.Array) -> jax.Array:
-        """All-leaves log density tensor (the paper's ``E``).
+    def log_density(self, x: jax.Array, theta: jax.Array,
+                    a: jax.Array) -> jax.Array:
+        """Log-densities ``log h(x) + sum_t T_t(x) theta_t - a`` of ``x``
+        under natural parameters ``theta`` (..., |T|) with ``a`` = A(theta),
+        elementwise: ``x``, ``theta[..., 0]`` and ``a`` broadcast together.
 
-        Args:
-          x:   (B, D) observations.
-          phi: (D, K, R, |T|) expectation parameters.
-
-        Returns:
-          (B, D, K, R) log-densities.
+        Each entry is formed on its own in float32: the sum over t is
+        unrolled over the static |T| (a depth-|T| contraction would use a
+        TPU's matrix unit badly), and ``a`` comes off each entry before any
+        sum over entries, since theta_t and A(theta) can be large and cancel.
         """
-        theta = self.expectation_to_natural(phi)  # (D, K, R, T)
-        t = self.sufficient_statistics(x)  # (B, D, T)
-        # inner product T(x)^T theta, broadcast over (K, R)
-        dot = jnp.einsum("bdt,dkrt->bdkr", t, theta,
-                         precision=layers.PRECISION)
-        a = self.log_normalizer(theta)  # (D, K, R)
-        return self.log_h(x)[:, :, None, None] + dot - a[None]
+        t = self.sufficient_statistics(x)
+        dot = t[..., 0] * theta[..., 0]
+        for i in range(1, self.num_stats):
+            dot = dot + t[..., i] * theta[..., i]
+        return self.log_h(x) + dot - a
 
 
 class Normal(ExponentialFamily):

@@ -184,12 +184,10 @@ def run_health_probe(archs, out_dir: str = "artifacts/health") -> int:
             else:
                 params = model.init(jax.random.PRNGKey(0))
                 x = jnp.asarray(_probe_data(model, PROBE_BATCH))
-                e = model.leaf_log_prob(params, x, None)
-                leaf_rows = model._leaf_rows(e)
+                leaf_rows = model.leaf_rows(params, x)
                 with health_lib.collect() as taps:
-                    root = model.forward_from_e(
-                        params["einsum"], params["mixing"], None,
-                        leaf_rows=leaf_rows,
+                    root = model.forward_from_leaves(
+                        params["einsum"], params["mixing"], leaf_rows
                     )
                 ll = jax.scipy.special.logsumexp(
                     root + jnp.log(params["class_prior"])[None, :], axis=-1

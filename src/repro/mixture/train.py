@@ -45,10 +45,9 @@ from repro.core.em import (
     EMConfig,
     accumulate_statistics,
     blend_params,
-    leaf_scatter,
+    leaf_statistics,
     m_step,
 )
-from repro.core import layers
 from repro.data.pipeline import ShardedLoader
 from repro.mixture.cluster import cluster_order
 from repro.mixture.model import EiNetMixture, _W_FLOOR
@@ -95,16 +94,13 @@ def mixture_em_statistics(
     comp = params["components"]
     weights = params["mixture_weights"]
 
-    def leaf_rows_one(p):
-        e = model.leaf_log_prob(p, x, None)
-        return model._leaf_rows(e)
-
-    leaf_rows = jax.vmap(leaf_rows_one)(comp)  # (C, B, num_leaves, K)
+    # (C, B, num_leaves, K)
+    leaf_rows = jax.vmap(lambda p: model.leaf_rows(p, x))(comp)
     logprior = jnp.log(comp["class_prior"])  # (C, num_classes)
 
     def batch_ll(einsum_s, mixing_s, lr_s, logprior_s, w):
         def root_one(ew, mv, lrc, lp):
-            root = model.forward_from_e(ew, mv, None, leaf_rows=lrc)
+            root = model.forward_from_leaves(ew, mv, lrc)
             return jax.scipy.special.logsumexp(root + lp[None, :], axis=-1)
 
         cll = jax.vmap(root_one, out_axes=1)(
@@ -123,20 +119,9 @@ def mixture_em_statistics(
     n_einsum = [w_ * g for w_, g in zip(comp["einsum"], g_einsum)]
     n_mixing = [v * g for v, g in zip(comp["mixing"], g_mixing)]
 
-    # leaf statistics: the single-model unique-index fan-out
-    # (core.em.leaf_scatter, the one shared definition), vmapped over C
-    ls = model.leaf_spec
-    t = model.ef.sufficient_statistics(x)  # (B, D, |T|), shared across comps
-    t_pairs = t[:, ls.pair_var, :]
-
-    def leaf_stats_one(g_leaf_c):
-        g_pairs = g_leaf_c[:, ls.pair_leaf, :]  # (B, P, K)
-        s_phi_pairs = jnp.einsum("bpk,bpt->pkt", g_pairs, t_pairs,
-                                 precision=layers.PRECISION)
-        s_den_pairs = jnp.sum(g_pairs, axis=0)
-        return leaf_scatter(model, s_phi_pairs, s_den_pairs)
-
-    s_phi, s_den = jax.vmap(leaf_stats_one)(g_leaf)
+    # leaf statistics: the single-model definition (core.em.leaf_statistics),
+    # vmapped over C with the batch shared
+    s_phi, s_den = jax.vmap(lambda g: leaf_statistics(model, g, x))(g_leaf)
     return {
         "n_einsum": n_einsum,
         "n_mixing": n_mixing,

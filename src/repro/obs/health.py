@@ -209,11 +209,10 @@ def health_vector(
     """
     spec = model.health_spec
     # -- dedicated health forward, tap sites armed
-    e = model.leaf_log_prob(params, probe_x, None)
-    leaf_rows = model._leaf_rows(e)
+    leaf_rows = model.leaf_rows(params, probe_x)
     with collect() as taps:
-        root = model.forward_from_e(
-            params["einsum"], params["mixing"], None, leaf_rows=leaf_rows
+        root = model.forward_from_leaves(
+            params["einsum"], params["mixing"], leaf_rows
         )
     if len(taps) != spec.num_segments:
         raise AssertionError(

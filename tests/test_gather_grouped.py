@@ -105,14 +105,14 @@ def test_gather_neg_inf_saturated_rows(impl):
     the gather kernel's -inf padding and stabilization clamps: bitwise
     forward parity and finite gradients on both paths."""
     m_g, m_p, params, x = _pair_models(4, 8, 2, 4, impl=impl)
-    lr = m_g._leaf_rows(m_g.leaf_log_prob(params, x, None))
+    lr = m_g.leaf_rows(params, x)
     # saturate one leaf rectangle: PD decompositions overlap, so siblings
     # keep the root finite while -inf rows flow through the kernel
     lr = lr.at[:, 0, :].set(NEG_INF)
 
     def root(m, rows):
-        return m.forward_from_e(params["einsum"], params["mixing"], None,
-                                leaf_rows=rows)
+        return m.forward_from_leaves(params["einsum"], params["mixing"],
+                                     rows)
 
     out_g = root(m_g, lr)
     out_p = root(m_p, lr)
@@ -127,7 +127,7 @@ def test_gather_neg_inf_saturated_rows(impl):
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 def test_gather_mixture_stacked_components(impl):
-    """The mixture trainer vmaps forward_from_e over stacked component
+    """The mixture trainer vmaps forward_from_leaves over stacked component
     params (repro.mixture); the gather-grouped op must be vmap-transparent
     on both impls."""
     m_g, m_p, _, x = _pair_models(2, 8, 2, 6, impl=impl)
@@ -136,8 +136,8 @@ def test_gather_mixture_stacked_components(impl):
 
     def comp_root(m):
         def one(p):
-            e = m.leaf_log_prob(p, x, None)
-            return m.forward_from_e(p["einsum"], p["mixing"], e)
+            return m.forward_from_leaves(p["einsum"], p["mixing"],
+                                         m.leaf_rows(p, x))
         return jax.vmap(one)(stacked)
 
     out_g = comp_root(m_g)

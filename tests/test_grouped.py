@@ -43,6 +43,8 @@ from repro.kernels import dispatch, grouped
 from repro.launch.cells import build_einet
 from repro.configs import get_config
 
+import leaf_reference
+
 # fully-canonical small RAT shapes (scope collisions at smaller var counts
 # break the canonical layout -- see random_binary_trees region dedup)
 CANONICAL_SHAPES = [
@@ -129,14 +131,16 @@ def test_grouped_neg_inf_saturated_rows():
     """NEG_INF-saturated leaf rows (fully-marginalized scopes) flow through
     the fused kernel's -inf padding contract: bitwise forward parity (the
     saturated rows leave this shape's contractions associated alike) and
-    finite gradients on both paths, equal to PALLAS_GRAD_ULPS."""
+    finite gradients on both paths, equal to PALLAS_GRAD_ULPS.  The leaf
+    rows are the dense reference's, so the kernels see the same inputs
+    whatever the leaf layer's rounding."""
     m_g, m_p, params, x = _pair_models(64, 3, 3, 10, 1, impl="pallas")
-    lr = m_g._leaf_rows(m_g.leaf_log_prob(params, x, None))
+    lr, _ = leaf_reference.leaf_rows(m_g, params, x, None)
     lr = lr.at[:, ::3, :].set(NEG_INF)  # saturate every third leaf row
 
     def root(m, rows):
-        out = m.forward_from_e(params["einsum"], params["mixing"], None,
-                               leaf_rows=rows)
+        out = m.forward_from_leaves(params["einsum"], params["mixing"],
+                                    rows)
         return out
 
     out_g = root(m_g, lr)
@@ -207,7 +211,7 @@ def test_vmem_budget_forces_segment_split_bitwise():
 
 
 def test_mixture_stacked_components_bitwise():
-    """The mixture trainer vmaps forward_from_e over stacked component
+    """The mixture trainer vmaps forward_from_leaves over stacked component
     params (repro.mixture); the grouped op must be vmap-transparent."""
     m_g, m_p, _, x = _pair_models(64, 3, 3, 6, 1)
     keys = jax.random.split(jax.random.PRNGKey(7), 3)
@@ -215,8 +219,8 @@ def test_mixture_stacked_components_bitwise():
 
     def comp_root(m):
         def one(p):
-            e = m.leaf_log_prob(p, x, None)
-            return m.forward_from_e(p["einsum"], p["mixing"], e)
+            return m.forward_from_leaves(p["einsum"], p["mixing"],
+                                         m.leaf_rows(p, x))
         return jax.vmap(one)(stacked)
 
     out_g = comp_root(m_g)

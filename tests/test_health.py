@@ -132,11 +132,9 @@ def test_pd_gather_taps():
     net = EiNet(g, num_sums=3, health=True)
     params = net.init(jax.random.PRNGKey(0))
     x = _x(net, b=8)
-    e = net.leaf_log_prob(params, x, None)
-    rows = net._leaf_rows(e)
+    rows = net.leaf_rows(params, x)
     with health_lib.collect() as taps:
-        net.forward_from_e(params["einsum"], params["mixing"], None,
-                           leaf_rows=rows)
+        net.forward_from_leaves(params["einsum"], params["mixing"], rows)
     assert len(taps) == net.health_spec.num_segments
     assert all(np.isfinite(float(t)) for t in taps)
 
