@@ -6,7 +6,8 @@ Files (all under ``bench/``):
 * ``configs/<config>.json``: a configuration's sizes; its ``reference``
   names the plain reference module beside it;
 * ``traffic/<mix>.json``: a traffic mix's parameters; ``driver`` names the
-  general driver that reads them (``train``: ``harness/train.py``);
+  general driver that reads them, ``harness/<driver>.py`` (the contract a
+  driver keeps is in :mod:`harness.main`);
 * ``limits/<cell>.json``: the limit of each number ``correct`` compares;
 * ``metrics/<metric>.py``: one per-layer metric's reader, ``read(run)``;
 * ``peaks.json``: per ``device_kind`` peak rates, with their source.
@@ -57,6 +58,15 @@ class Cell:
     def reference(self):
         return load_module(self.bench / "configs" / f"{self.config['reference']}.py")
 
+    def driver(self):
+        """The module ``harness/<driver>.py`` that the traffic mix names."""
+        name = self.traffic["driver"]
+        path = self.bench / "harness" / f"{name}.py"
+        if not path.is_file():
+            raise FileNotFoundError(f"traffic {self.workload['traffic']!r} names driver "
+                                    f"{name!r}, and there is no {path}")
+        return load_module(path)
+
     def metrics(self, section: str) -> List[dict]:
         """The metrics of ``end_to_end`` or ``per_layer`` this cell reports."""
         return [m for m in self.spec[section]
@@ -64,9 +74,11 @@ class Cell:
 
 
 def load_module(path: pathlib.Path):
-    name = "bench_" + path.stem.replace("-", "_").replace(".", "_")
-    if name in sys.modules:
-        return sys.modules[name]
+    """The module in the file ``path``, loaded once per file."""
+    name = f"bench_{path.parent.name}_{path.stem}".replace("-", "_").replace(".", "_")
+    mod = sys.modules.get(name)
+    if mod is not None and pathlib.Path(mod.__file__) == pathlib.Path(path):
+        return mod
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     sys.modules[name] = mod
@@ -171,18 +183,35 @@ class Tracer:
     """Profiler over a measured window (when ``on``).  Its ``bench.window``
     span covers the first ``seconds``; the profiler itself starts before the
     window and is stopped only after it, so its start and its teardown fall
-    outside what the window times."""
+    outside what the window times.
 
-    def __init__(self, on: bool, seconds: float):
+    While the profiler runs, ``repro.obs`` tracing is on too (unless
+    ``obs`` is False), so the program's spans land in the trace.  A driver
+    hands the tracer each compiled program that the window runs
+    (:meth:`add_program`), so that every device op is charged to its named
+    scope."""
+
+    def __init__(self, on: bool, seconds: float, obs: bool = True):
         self.on = on
         self.seconds = seconds
+        self.obs = obs
+        self.op_scopes: Dict[str, str] = {}
         self.dir: Optional[str] = None
         self.running = False
         self.open = False
         self._window = None
 
+    def add_program(self, hlo_text: str) -> None:
+        """Look up each op's scope in a compiled program's HLO text
+        (``jax.stages.Compiled.as_text()``); called in set-up, only when
+        ``on``."""
+        from harness import scopes
+
+        self.op_scopes.update(scopes.hlo_op_scopes(hlo_text))
+
     def start(self):
-        """Start the profiler (before the window opens)."""
+        """Start the profiler, then ``repro.obs`` tracing (before the window
+        opens)."""
         if not self.on:
             return
         import tempfile
@@ -192,6 +221,10 @@ class Tracer:
         self.dir = tempfile.mkdtemp(prefix="bench_trace_")
         jax.profiler.start_trace(self.dir)
         self.running = True
+        if self.obs:
+            from repro import obs
+
+            obs.configure(trace=True)
 
     def open_window(self):
         """Open the ``bench.window`` span: the first thing the window does."""
@@ -209,33 +242,51 @@ class Tracer:
             self.open = False
 
     def stop(self):
-        """Close the span if still open and stop the profiler (after the
-        window has closed)."""
+        """Close the span if still open, turn ``repro.obs`` tracing off and
+        stop the profiler (after the window has closed)."""
         if self.open:
             self._window.stop()
             self.open = False
         if self.running:
             import jax
 
+            if self.obs:
+                from repro import obs
+
+                obs.configure(trace=False)
             jax.profiler.stop_trace()
             self.running = False
 
-    def reduce(self) -> Optional[dict]:
-        if self.dir is None:
-            return None
+    def events(self) -> List[tuple]:
+        """Stop, flatten the trace with the program's spans and each device
+        op's scope (:func:`harness.scopes.events_from_xplane`), and remove
+        it."""
         import shutil
 
-        from harness import trace
+        from harness import scopes, trace
 
         self.stop()
         try:
-            events = trace.events_from_xplane(trace.find_xplane(self.dir))
+            return scopes.events_from_xplane(trace.find_xplane(self.dir), self.op_scopes)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
-        window = trace.spans(events, "bench.window")
-        if not window:
-            raise RuntimeError("the trace holds no bench.window span")
-        return trace.reduce_trace(events, window[0])
+            self.dir = None
+
+    def reduce(self) -> Optional[dict]:
+        """:func:`reduce_window` of the trace; None where nothing was traced."""
+        if self.dir is None:
+            return None
+        return reduce_window(self.events())
+
+
+def reduce_window(events: List[tuple]) -> dict:
+    """:func:`harness.scopes.reduce` over the ``bench.window`` span."""
+    from harness import scopes, trace
+
+    window = trace.spans(events, "bench.window")
+    if not window:
+        raise RuntimeError("the trace holds no bench.window span")
+    return scopes.reduce(events, window[0])
 
 
 def make_weights(ref, layout, seed_word: int):

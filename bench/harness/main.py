@@ -8,6 +8,34 @@ with ``--trace 1`` the profiler records the first seconds of the window and
 the metrics are the cell's per-layer metrics, with ``busy_s``, ``window_s``
 and a ``breakdown``.  The numbers ``correct`` compares come last, in the
 line under ``checks`` and as the last lines on standard error.
+
+The driver is found by name: a traffic mix's ``driver`` names the module
+``bench/harness/<driver>.py`` (:meth:`harness.core.Cell.driver`), so a new
+driver and a traffic file that names it run a cell with no edit here.  A
+driver keeps this contract:
+
+* ``run(cell, seed, seconds, tracer, counter, t_process, devs) -> dict``:
+  set-up from the seed, then the window of ``seconds``, then the
+  comparison with the reference.  ``tracer`` (:class:`harness.core.Tracer`)
+  is started just before the window (``tracer.start()``), opened as its
+  first act (``open_window()``), ticked once per step (``tick()``) and
+  stopped once the window's work is done (``stop()``); ``counter``
+  (:class:`harness.core.CompileCounter`) is ``active`` over the window
+  alone; ``devs`` are the cell's chips.
+* Where ``tracer.on``, set-up hands the tracer the compiled HLO text of
+  each program that the window runs, before the window
+  (``tracer.add_program(compiled.as_text())``), so the traced run charges
+  every device op to its named scope.  Where it is off, the driver does
+  nothing more than an untraced run needs.
+* Keys every driver returns: ``kind`` (what the readers switch on, such as
+  ``train``), ``e2e`` (the cell's end-to-end metrics by name), ``attempted``
+  and ``failed`` (counts of the window's work), ``checks``
+  (:class:`harness.core.Checks`) and ``memory_peak_bytes``.
+* Keys the per-layer readers use, where the kind has them: ``batch`` (rows
+  per step), ``work`` (:func:`harness.work.counts`) and
+  ``input_ms_per_step``.  This module adds ``chips``, ``peak``, ``trace``
+  (:func:`harness.core.reduce_window` of the traced run, else None) and
+  ``compiles_in_window``.
 """
 
 from __future__ import annotations
@@ -44,12 +72,7 @@ def run_cell(cell: core.Cell, seed: int, seconds: float, trace: bool,
         core.enable_compile_cache()
     counter = core.CompileCounter()
     tracer = core.Tracer(trace, min(TRACE_SECONDS, seconds))
-    driver = cell.traffic["driver"]
-    if driver != "train":
-        raise KeyError(f"unknown driver {driver!r}")
-    from harness import train as drv
-
-    run = drv.run(cell, seed, seconds, tracer, counter, t_process, devs)
+    run = cell.driver().run(cell, seed, seconds, tracer, counter, t_process, devs)
     core.log(f"compiles inside the window: {counter.count} {counter.names[:6]}; "
              f"persistent cache over the run: {counter.cache}")
     run["compiles_in_window"] = counter.count
@@ -75,10 +98,18 @@ def run_cell(cell: core.Cell, seed: int, seconds: float, trace: bool,
     if reduced is not None:
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
-        result["breakdown"] = {"device_ops": reduced["device_ops"],
-                               "idle_gaps": reduced["idle_gaps"]}
+        result["breakdown"] = breakdown(reduced)
     result["checks"] = run["checks"].table()
     return result
+
+
+def breakdown(reduced: Dict) -> Dict:
+    """The ten device ops that took most time, each named with its program
+    scope where it has one, and the ten longest shares of idle time by the
+    innermost span, the benchmark's or the program's, that the host was in."""
+    ops = [[f"{op} ({reduced['op_scopes'][op]})" if reduced["op_scopes"].get(op) else op, s]
+           for op, s in reduced["device_ops"]]
+    return {"device_ops": ops, "idle_gaps": reduced["idle_split"][:10]}
 
 
 def main(t_process: float, argv: Optional[List[str]] = None) -> int:

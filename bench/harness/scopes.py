@@ -1,9 +1,9 @@
-"""Reduction of a ``jax.profiler`` trace that holds the program's own spans
-and named scopes: idle gaps split by the innermost host span, device time
-per named scope, and the per-step layer times read from them.
+"""The flattening of a ``jax.profiler`` trace, and its reduction with the
+program's own spans and named scopes: idle gaps split by the innermost host
+span, device time per named scope, and the per-step layer times read from
+them, beside :func:`harness.trace.reduce_trace`'s busy time and top ops.
 
-The program writes two things into a trace that :mod:`harness.trace` (the
-reduction of the accepted per-layer metrics) leaves out:
+The program writes two things into the trace besides its device ops:
 
 * ``repro.obs`` spans on the host plane (``train.copy``,
   ``train.dispatch``, ``train.sync``, ``train.record``; ``serve.*``), when
@@ -17,10 +17,10 @@ reduction of the accepted per-layer metrics) leaves out:
   within a program.  A fusion carries the ``op_name`` of its root op, so a
   fusion is charged to the scope of its root.
 
-Events are the 5-tuples of :mod:`harness.trace` with the op's innermost
-program scope appended (``""`` where it has none), so
-:func:`harness.trace.reduce_trace` reads them unchanged; :func:`reduce`
-reads 5-tuples too, as ops under no scope.
+Events are ``(plane, line, name, start_ns, duration_ns, scope)``: the op's
+innermost program scope last (``""`` where it has none).
+:func:`harness.trace.reduce_trace` reads the first five fields alone, and
+:func:`reduce` reads 5-tuples too, as ops under no scope.
 """
 
 from __future__ import annotations
@@ -63,9 +63,10 @@ def is_program_span(name: str) -> bool:
 
 
 def events_from_xplane(path: str, op_scopes: Dict[str, str]) -> List[tuple]:
-    """Flatten one ``.xplane.pb`` file as :func:`harness.trace.events_from_xplane`
-    does, keeping besides the program's host spans, and each device op's
-    scope (looked up by op name in ``op_scopes``) as a sixth field."""
+    """Flatten the device and host planes of one ``.xplane.pb`` file: each
+    device op and program run, the host's program launches, the benchmark's
+    spans and the program's, each device op's scope (looked up by op name
+    in ``op_scopes``) as a sixth field."""
     import jax
 
     data = jax.profiler.ProfileData.from_file(path)
@@ -111,13 +112,36 @@ def split_gap(spans: Sequence[Tuple[float, float, str]], a: float,
     return out
 
 
+def holders(ops: Sequence[tuple]) -> set:
+    """Indices of the ops (start, end, scope, ...) of one chip whose interval
+    holds a shorter op of the same scope: a loop op (``while``) and the ops
+    of its body are all reported, and the body's ops alone are the work."""
+    out = set()
+    by_scope: Dict[str, List[int]] = collections.defaultdict(list)
+    for i, op in enumerate(ops):
+        by_scope[op[2]].append(i)
+    for idx in by_scope.values():
+        idx.sort(key=lambda i: (ops[i][0], -ops[i][1]))
+        for k, i in enumerate(idx):
+            a, b = ops[i][0], ops[i][1]
+            m = k + 1
+            while m < len(idx) and ops[idx[m]][0] < b:
+                j = idx[m]
+                if ops[j][1] <= b and ops[j][1] - ops[j][0] < b - a:
+                    out.add(i)
+                    break
+                m += 1
+    return out
+
+
 def reduce(events: Sequence[tuple], window: Tuple[float, float]) -> Dict:
     """:func:`harness.trace.reduce_trace` of the window, and beside it:
 
     * ``idle_split``: chip 0's idle gaps split by the innermost span, from
       the benchmark or the program (:func:`split_gap`), in s;
     * ``scope_seconds``: device time per innermost program scope, mean over
-      chips, clipped to the window (``""``: ops under no program scope);
+      chips, clipped to the window (``""``: ops under no program scope); an
+      op that holds others of its scope (:func:`holders`) is left out;
     * ``op_scopes``: each op name's scope;
     * ``span_seconds``: host time per program span name, clipped to the
       window;
@@ -135,10 +159,11 @@ def reduce(events: Sequence[tuple], window: Tuple[float, float]) -> Dict:
         ops = [(e[3] + off, e[3] + off + e[4], e[5] if len(e) > 5 else "",
                 trace.op_name(e[2]))
                for e in events if e[0] == chip and e[1] == "XLA Ops"]
-        for a, b, scope, op in ops:
+        held = holders(ops)
+        for k, (a, b, scope, op) in enumerate(ops):
             op_scopes[op] = scope
             a2, b2 = max(a, lo), min(b, hi)
-            if b2 > a2:
+            if b2 > a2 and k not in held:
                 scope_ns[scope] += (b2 - a2) / len(names)
         if i == 0:
             busy = trace.union(trace.clip([(a, b) for a, b, _, _ in ops], lo, hi))

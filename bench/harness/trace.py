@@ -1,10 +1,10 @@
-"""Reduction of a ``jax.profiler`` trace to device busy time, idle gaps
-labelled by the benchmark's host spans, and the top device operations.
+"""Reduction of a ``jax.profiler`` trace to device busy time, the top
+device operations and the benchmark's spans inside the window.
 
-The trace is first flattened into plain event tuples
-``(plane, line, name, start_ns, duration_ns)`` by :func:`events_from_xplane`;
-everything else works on that list, so the self-checks run it on a small
-recorded trace without a chip.
+The trace is first flattened into event tuples
+``(plane, line, name, start_ns, duration_ns[, scope])`` by
+:func:`harness.scopes.events_from_xplane`; everything else works on that
+list, so the self-checks run it on a small recorded trace without a chip.
 
 Device planes are ``/device:TPU:<n>``; their ``XLA Ops`` line holds one
 event per executed HLO operation and ``XLA Modules`` one per program run.
@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import collections
 import os
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Dict, Iterable, List, Sequence, Tuple
 
 Event = Tuple[str, str, str, float, float]  # plane, line, name, start_ns, dur_ns
 
@@ -27,25 +27,6 @@ DEVICE_PREFIX = "/device:TPU:"
 HOST_PLANE = "/host:CPU"
 LAUNCH = "PJRT_LoadedExecutable_Execute"
 SPAN_PREFIX = "bench."
-
-
-def events_from_xplane(path: str) -> List[Event]:
-    """Flatten the device and host planes of one ``.xplane.pb`` file."""
-    import jax
-
-    data = jax.profiler.ProfileData.from_file(path)
-    out: List[Event] = []
-    for plane in data.planes:
-        if not (plane.name.startswith(DEVICE_PREFIX) or plane.name == HOST_PLANE):
-            continue
-        for line in plane.lines:
-            if plane.name.startswith(DEVICE_PREFIX) and line.name not in ("XLA Ops", "XLA Modules"):
-                continue
-            for e in line.events:
-                if plane.name == HOST_PLANE and not (e.name.startswith(SPAN_PREFIX) or e.name == LAUNCH):
-                    continue
-                out.append((plane.name, line.name, e.name, float(e.start_ns), float(e.duration_ns)))
-    return out
 
 
 def find_xplane(directory: str) -> str:
@@ -97,17 +78,15 @@ def spans(events: Sequence[Event], name: str) -> List[Tuple[float, float]]:
 
 def reduce_trace(events: Sequence[Event], window: Tuple[float, float]) -> Dict:
     """Busy and idle time of every chip inside ``window`` (host-clock ns),
-    the top device operations, the idle gaps of chip 0 labelled by the
-    innermost benchmark span that covers most of each gap, and how many of
-    each benchmark span lie wholly inside the window."""
+    the top device operations, and how many of each benchmark span lie
+    wholly inside the window."""
     lo, hi = window
     per_chip = []
     op_time: Dict[str, float] = collections.Counter()
-    gaps_by_label: Dict[str, float] = collections.Counter()
     host_spans = [(e[3], e[3] + e[4], e[2]) for e in events
                   if e[0] == HOST_PLANE and e[2].startswith(SPAN_PREFIX) and e[2] != "bench.window"]
     names = chips(events)
-    for i, chip in enumerate(names):
+    for chip in names:
         off = host_offset_ns(events, chip)
         ops = [(e[3] + off, e[3] + off + e[4], e[2]) for e in events
                if e[0] == chip and e[1] == "XLA Ops"]
@@ -118,11 +97,6 @@ def reduce_trace(events: Sequence[Event], window: Tuple[float, float]) -> Dict:
             a2, b2 = max(a, lo), min(b, hi)
             if b2 > a2:
                 op_time[op_name(name)] += (b2 - a2) / len(names)
-        if i == 0:
-            edges = [lo] + [x for iv in busy for x in iv] + [hi]
-            for a, b in zip(edges[0::2], edges[1::2]):
-                if b > a:
-                    gaps_by_label[label_gap(host_spans, a, b)] += b - a
     window_ns = hi - lo
     inside = collections.Counter(name for s, e, name in host_spans if s >= lo and e <= hi)
     busy_mean = sum(c["busy_ns"] for c in per_chip) / max(len(per_chip), 1)
@@ -134,19 +108,5 @@ def reduce_trace(events: Sequence[Event], window: Tuple[float, float]) -> Dict:
         "spans_inside": dict(inside),
         "op_seconds": {k: v * 1e-9 for k, v in op_time.items()},
         "device_ops": [[k, v * 1e-9] for k, v in op_time.most_common(10)],
-        "idle_gaps": [[k, v * 1e-9] for k, v in gaps_by_label.most_common(10)],
     }
 
-
-def label_gap(host_spans, a: float, b: float) -> str:
-    """The span covering most of [a, b); among equals, the shortest (the
-    innermost).  ``host`` when no benchmark span overlaps the gap."""
-    best: Optional[Tuple[float, float, str]] = None
-    for s, e, name in host_spans:
-        cover = min(e, b) - max(s, a)
-        if cover <= 0:
-            continue
-        key = (cover, -(e - s), name)
-        if best is None or key > best:
-            best = key
-    return best[2] if best else "host"

@@ -10,7 +10,10 @@ batch, then the step, then ``float(ll)``).
 Set-up builds that one step, drives it from the seed through its first
 three steps on the loader's first three batches, and hands the same step
 and state to the window.  ``correct`` compares those three steps with the
-reference (see :func:`check`).
+reference (see :func:`check`).  In a traced run set-up also hands the
+tracer the step's compiled HLO text: on one chip that of the registry's
+``make_em_step(model, TrainConfig())``, the program ``fit`` runs, lowered
+for the cell's batch; on several, that of the sharded step.
 """
 
 from __future__ import annotations
@@ -25,18 +28,24 @@ REF_BLOCK = 512
 
 
 def make_runner(model, cell: core.Cell, devs):
-    """(params, batches) -> (params, [mean LL per step]): the path the
-    window drives, built once."""
+    """The path the window drives, built once: (params, batches) ->
+    (params, [mean LL per step]); the context it runs in; and (params) ->
+    the compiled HLO text of its step at the cell's batch."""
     import jax
     import jax.numpy as jnp
 
-    from repro.train import TrainConfig, fit
+    from repro.train import TrainConfig, fit, make_em_step
+
+    x = jax.ShapeDtypeStruct((cell.traffic["batch"], model.num_vars), jnp.float32)
 
     if cell.chips == 1:
         def run(params, batches):
             return fit(model, params, batches, TrainConfig())
 
-        return run, core.no_op
+        def program(params):
+            return make_em_step(model, TrainConfig()).lower(params, x).compile().as_text()
+
+        return run, core.no_op, program
 
     from repro.dist import sharding as shlib
     from repro.launch.mesh import make_mesh_for
@@ -63,7 +72,10 @@ def make_runner(model, cell: core.Cell, devs):
             lls.append(float(ll))
         return params, lls
 
-    return run, context
+    def program(params):
+        return step.lower(params, x).compile().as_text()
+
+    return run, context, program
 
 
 def run(cell: core.Cell, seed: int, seconds: float, tracer: core.Tracer,
@@ -88,7 +100,7 @@ def run(cell: core.Cell, seed: int, seconds: float, tracer: core.Tracer,
     core.log(f"set-up: weights done at {time.perf_counter() - t_process:.2f} s")
     rows = data.rows(cfg, tr["rows"], words[1])
     loader = train_cli.einet_loader(rows, tr["batch"])
-    runner, context = make_runner(model, cell, devs)
+    runner, context, program = make_runner(model, cell, devs)
 
     with context():
         # the first steps: compile, and the states the reference follows
@@ -103,6 +115,10 @@ def run(cell: core.Cell, seed: int, seconds: float, tracer: core.Tracer,
         snaps.append(jax.device_get(params))
         setup_s = time.perf_counter() - t_process
         core.log(f"set-up: data and {WARM_STEPS} steps done at {setup_s:.2f} s")
+        if tracer.on:
+            tracer.add_program(program(params))
+            core.log(f"set-up: scopes of {len(tracer.op_scopes)} ops at "
+                     f"{time.perf_counter() - t_process:.2f} s")
 
         # the window
         stats = {"input_s": 0.0, "steps": 0, "ends": []}

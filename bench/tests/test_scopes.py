@@ -12,6 +12,7 @@ import pytest
 from harness import core, scopes, trace
 
 DATA = pathlib.Path(__file__).parent / "data"
+METRICS = pathlib.Path(__file__).resolve().parents[1] / "metrics"
 
 
 def load(name):
@@ -67,7 +68,7 @@ def test_idle_split_by_innermost_span(reduced):
 
 
 def test_device_time_per_scope(reduced):
-    want = {"einet.leaf": 600, "em.leaf_stats": 400, "plan.gather": 1600,
+    want = {"einet.leaf": 560, "em.leaf_stats": 400, "plan.gather": 1600,
             "plan.layer": 600, "em.mstep": 400, "em.estep": 200}
     assert reduced["scope_seconds"] == pytest.approx({k: v * 1e-9 for k, v in want.items()})
     assert reduced["op_scopes"]["copy.5"] == "plan.layer"
@@ -78,16 +79,63 @@ def test_layer_ms(reduced):
     # per step, in ms: two bench.step spans lie inside the window
     assert scopes.layer_ms(reduced) == pytest.approx({
         "train_copy_ms": 4e-4, "train_dispatch_ms": 2e-4, "train_sync_ms": 1.9e-3,
-        "train_leaf_ms": 5e-4, "train_einsum_ms": 1.1e-3, "train_mstep_ms": 2e-4})
+        "train_leaf_ms": 4.8e-4, "train_einsum_ms": 1.1e-3, "train_mstep_ms": 2e-4})
+
+
+NEW_METRICS = ("train_copy_ms", "train_dispatch_ms", "train_sync_ms",
+               "train_leaf_ms", "train_einsum_ms", "train_mstep_ms")
+
+
+@pytest.mark.parametrize("name", NEW_METRICS)
+def test_layer_readers(reduced, name):
+    read = core.load_module(METRICS / f"{name}.py").read
+    assert read({"kind": "train", "trace": reduced}) == scopes.layer_ms(reduced)[name]
+    assert read({"kind": "train", "trace": None}) is None
+    events = load("small_trace.json")
+    bare = scopes.reduce(events, trace.spans(events, "bench.window")[0])
+    assert read({"kind": "train", "trace": bare}) is None
+
+
+def test_loop_op_counted_once(reduced):
+    """The loop op ``while.3`` holds its body's two trips of ``fusion.11``:
+    the leaf layer is the body's ops and the other step's ``fusion.1``
+    alone, while the accepted reduction still lists the loop op."""
+    body = 2 * 130 + 300
+    assert reduced["scope_seconds"]["einet.leaf"] == pytest.approx(body * 1e-9)
+    ops = reduced["op_seconds"]
+    assert ops["while.3"] == pytest.approx(300e-9)
+    assert reduced["scope_seconds"]["einet.leaf"] == pytest.approx(
+        sum(v for op, v in ops.items()
+            if reduced["op_scopes"][op] == "einet.leaf" and op != "while.3"))
+
+
+@pytest.mark.parametrize("ops,held", [
+    ([(0, 10, "a"), (1, 5, "a"), (5, 9, "a")], {0}),
+    ([(0, 10, "a"), (1, 5, "b")], set()),
+    ([(0, 10, "a"), (0, 10, "a")], set()),
+    ([(0, 5, "a"), (5, 9, "a")], set()),
+    ([(0, 10, "a"), (2, 8, "a"), (3, 4, "a")], {0, 1}),
+])
+def test_holders(ops, held):
+    assert scopes.holders(ops) == held
 
 
 def test_accepted_reduction_unchanged(reduced):
     """The accepted reduction reads the new flattening as it reads its own:
-    program spans and scopes move none of its numbers."""
+    program spans and scopes move none of its numbers, and the accepted
+    per-layer metrics read the same from the benchmark's reduction
+    (:func:`harness.scopes.reduce`) as from the accepted one."""
     events = load("scoped_trace.json")
     window = trace.spans(events, "bench.window")[0]
     plain = [e[:5] for e in events if not scopes.is_program_span(e[2])]
-    assert trace.reduce_trace(events, window) == trace.reduce_trace(plain, window)
+    accepted = trace.reduce_trace(plain, window)
+    assert trace.reduce_trace(events, window) == accepted
+    assert {k: v for k, v in reduced.items() if k in accepted} == accepted
+    run = {"kind": "train", "batch": 512, "work": {"train_flops": 3.95e6},
+           "input_ms_per_step": 0.8, "chips": 1, "peak": {"bf16_flops_per_s": 1.97e14}}
+    for name in ("train_input_ms", "train_mfu", "train_idle_share"):
+        read = core.load_module(METRICS / f"{name}.py").read
+        assert read(dict(run, trace=reduced)) == read(dict(run, trace=accepted)) is not None
 
 
 def test_nothing_to_read_without_spans_or_scopes():

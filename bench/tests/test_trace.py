@@ -1,11 +1,11 @@
 """The trace reduction on a small recorded trace: busy union, idle share,
-the device clock offset, and idle gaps labelled by host spans."""
+the device clock offset, and idle gaps split by the benchmark's spans."""
 
 import json
 import pathlib
 
 import pytest
-from harness import trace
+from harness import scopes, trace
 
 DATA = pathlib.Path(__file__).parent / "data" / "small_trace.json"
 
@@ -33,14 +33,12 @@ def test_reduce_busy_idle_and_gaps(events):
     assert r["busy_s"] == pytest.approx(1400e-9)
     assert r["window_s"] == pytest.approx(5000e-9)
     assert r["idle_share"] == pytest.approx(0.72)
-    gaps = dict(r["idle_gaps"])
-    # [0, 1000): loader 200-1100 covers 700, step 50; [1500, 1600): step;
-    # [2000, 3050): sleep covers 1000, step 100; [3550, 5000): step 100
-    assert gaps["bench.loader"] == pytest.approx(1000e-9)
-    assert gaps["bench.step"] == pytest.approx(1550e-9)
-    assert gaps["bench.sleep"] == pytest.approx(1050e-9)
-    assert "host" not in gaps
-    assert trace.label_gap([], 0, 10) == "host"
+    # the gaps, split by the innermost span: [0, 1000): loader 200-900, step
+    # from 950; [1500, 1600): step; [2000, 3050): sleep 2000-2950, then step;
+    # [3550, 5000): step to 3650, then none
+    gaps = dict(scopes.reduce(events, window)["idle_split"])
+    want = {"host": 1600, "bench.loader": 700, "bench.step": 350, "bench.sleep": 950}
+    assert gaps == pytest.approx({k: v * 1e-9 for k, v in want.items()})
     ops = dict(r["device_ops"])
     assert ops["fusion.1"] == pytest.approx(1000e-9)
     assert ops["all-reduce.2"] == pytest.approx(300e-9)

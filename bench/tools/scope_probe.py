@@ -1,15 +1,16 @@
-"""Run one cell with the profiler and ``repro.obs`` tracing on over the
-window, and read its trace two ways: as the accepted per-layer metrics read
-it (``harness/trace.py``), and by the program's own spans and named scopes
-(``harness/scopes.py``).
+"""Run one cell with the benchmark's tracer (the profiler and ``repro.obs``
+tracing on over the window), and print what its trace holds beyond the
+result line: the accepted per-layer metrics read from the accepted
+flattening (``harness/trace.py``, no program spans) beside the benchmark's
+(``harness/scopes.py``), the share of device time the layers cover, the
+idle split, and the ops under no layer's scope.
 
     python3 bench/tools/scope_probe.py --workload pd_svhn.em_b512 --seed 7 --seconds 10
 
-``--obs 0`` leaves ``repro.obs`` tracing off (the profiler alone, as a
-``--trace 1`` run of ``bench/run.py``), to read what the program's spans
-cost.  Prints a summary and writes it, with the scope of every device op
-(from the step's compiled HLO text), to
-``chiprun_out/scope_probe_<cell>_<seed>.json``.
+``--obs 0`` turns the tracer's ``repro.obs`` switch off (the profiler
+alone), to read what the program's spans cost.  Prints a summary and writes
+it, with the scope of every device op (from the step's compiled HLO text),
+to ``chiprun_out/scope_probe_<cell>_<seed>.json``.
 """
 import time
 
@@ -25,52 +26,18 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__
 sys.path[:0] = [os.path.join(_ROOT, "src"), os.path.join(_ROOT, "bench")]
 
 from harness import core, scopes, trace  # noqa: E402
-from harness import train as drv  # noqa: E402
 from harness.main import TRACE_SECONDS  # noqa: E402
 
-class ObsTracer(core.Tracer):
-    """The benchmark's tracer, with ``repro.obs`` tracing on exactly while
-    the profiler runs."""
 
-    def __init__(self, seconds: float, obs_on: bool):
-        super().__init__(True, seconds)
-        self.obs_on = obs_on
-
-    def start(self):
-        super().start()
-        if self.obs_on:
-            from repro import obs
-
-            obs.configure(trace=True)
-
-    def stop(self):
-        if self.running and self.obs_on:
-            from repro import obs
-
-            obs.configure(trace=False)
-        super().stop()
-
-
-def hlo_scopes(cell):
-    """Op name -> scope, from the compiled HLO text of the cell's EM step."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.launch import cells as cells_lib
-    from repro.train import TrainConfig, make_em_step
-
-    model = cells_lib.build_einet(core.program_config(cell.config))
-    params = model.init(jax.random.PRNGKey(0))
-    x = jnp.zeros((cell.traffic["batch"], model.num_vars), jnp.float32)
-    return scopes.hlo_op_scopes(
-        make_em_step(model, TrainConfig()).lower(params, x).compile().as_text())
+# the per-layer metrics that read the accepted reduction (harness/trace.py)
+ACCEPTED = ("train_input_ms", "train_mfu", "train_idle_share")
 
 
 def read_accepted(cell, run, reduced):
-    """The accepted per-layer metrics of the cell, read from ``reduced``."""
+    """The cell's per-layer metrics of :data:`ACCEPTED`, read from ``reduced``."""
     run = dict(run, trace=reduced)
     return {m["name"]: core.load_module(cell.bench / "metrics" / f"{m['name']}.py").read(run)
-            for m in cell.metrics("per_layer")}
+            for m in cell.metrics("per_layer") if m["name"] in ACCEPTED}
 
 
 def probe(cell, seed: int, seconds: float, obs_on: bool = True,
@@ -79,20 +46,18 @@ def probe(cell, seed: int, seconds: float, obs_on: bool = True,
     if require_tpu:
         core.enable_compile_cache()
     counter = core.CompileCounter()
-    tracer = ObsTracer(min(TRACE_SECONDS, seconds), obs_on)
-    run = drv.run(cell, seed, seconds, tracer, counter, t_process, devs)
+    tracer = core.Tracer(True, min(TRACE_SECONDS, seconds), obs=obs_on)
+    run = cell.driver().run(cell, seed, seconds, tracer, counter, t_process, devs)
     core.log(f"compiles inside the window: {counter.count} {counter.names[:6]}")
-    path = trace.find_xplane(tracer.dir)
     run.update(chips=cell.chips, peak=core.peaks(devs[0].device_kind) if require_tpu else None)
 
-    # the accepted reduction, on the accepted flattening and on the new one
-    old = trace.events_from_xplane(path)
-    window = trace.spans(old, "bench.window")[0]
-    accepted = read_accepted(cell, run, trace.reduce_trace(old, window))
-
-    op_scopes = hlo_scopes(cell) if require_tpu else {}
-    events = scopes.events_from_xplane(path, op_scopes)
-    red = scopes.reduce(events, window)
+    # the accepted metrics, on the accepted flattening and on the benchmark's
+    op_scopes = tracer.op_scopes
+    events = tracer.events()
+    red = core.reduce_window(events)
+    window = trace.spans(events, "bench.window")[0]
+    plain = [e[:5] for e in events if not scopes.is_program_span(e[2])]
+    accepted = read_accepted(cell, run, trace.reduce_trace(plain, window))
     layers = scopes.layer_ms(red)
     steps = red["steps"]
     busy_ms = 1e3 * red["busy_s"] / steps if steps else None
@@ -114,7 +79,7 @@ def probe(cell, seed: int, seconds: float, obs_on: bool = True,
         "layers_ms": layers, "busy_ms_per_step": busy_ms,
         "covered_share": covered / busy_ms if busy_ms else None,
         "idle_s": red["window_s"] - red["busy_s"],
-        "idle_split": red["idle_split"], "idle_gaps_accepted": red["idle_gaps"],
+        "idle_split": red["idle_split"],
         "scope_seconds": red["scope_seconds"], "span_seconds": red["span_seconds"],
         "steps": steps,
         "device_ops": [[op, sec, red["op_scopes"].get(op, "")]
